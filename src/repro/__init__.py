@@ -1,2 +1,1 @@
 """repro — Triton-distributed (overlapping distributed kernels) on TPU in JAX."""
-from . import _compat  # noqa: F401  (backfills jax API names; must be first)

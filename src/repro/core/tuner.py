@@ -62,7 +62,7 @@ def analytic_ag_matmul(
     world: int,
     *,
     dtype_bytes: int = 2,
-    spec: hw.HardwareSpec = hw.DEFAULT,
+    spec: Optional[hw.HardwareSpec] = None,
     candidates: Optional[Sequence[str]] = None,
     max_sub: int = 4,
 ) -> OverlapChoice:
@@ -82,6 +82,7 @@ def analytic_ag_matmul(
     riding-chunk bytes (``ops.wire.wire_bytes`` — payload + per-row
     scales) and charges the codec passes to the compute side.
     """
+    spec = spec or hw.local_spec()
     if candidates is None:
         candidates = overlap.transports_for("ag_matmul", include_baseline=True)
     f32_bytes = m_loc * k * dtype_bytes
@@ -155,7 +156,7 @@ def analytic_matmul_rs(
     world: int,
     *,
     dtype_bytes: int = 2,
-    spec: hw.HardwareSpec = hw.DEFAULT,
+    spec: Optional[hw.HardwareSpec] = None,
     candidates: Optional[Sequence[str]] = None,
     max_sub: int = 4,
 ) -> OverlapChoice:
@@ -172,6 +173,7 @@ def analytic_matmul_rs(
     pays encode+decode passes EVERY hop (the ring re-encodes the
     accumulator each step), so it only wins where the ICI term binds.
     """
+    spec = spec or hw.local_spec()
     if candidates is None:
         candidates = overlap.transports_for("matmul_rs", include_baseline=True)
     m_blk = m // world
@@ -255,7 +257,7 @@ def analytic_ring_attention(
     causal: bool = True,
     heads: int = 1,
     dtype_bytes: int = 2,
-    spec: hw.HardwareSpec = hw.DEFAULT,
+    spec: Optional[hw.HardwareSpec] = None,
     candidates: Optional[Sequence[str]] = None,
     placements: Optional[Sequence[str]] = None,
 ) -> OverlapChoice:
@@ -272,6 +274,7 @@ def analytic_ring_attention(
     are FLOP-identical, so the enumeration keeps contiguous (strict-<
     selection, contiguous first).
     """
+    spec = spec or hw.local_spec()
     if candidates is None:
         candidates = overlap.transports_for("ring_attention",
                                             include_baseline=False)
@@ -349,7 +352,7 @@ def recommend_overlap_modes(
     world: int,
     *,
     dtype_bytes: int = 2,
-    spec: hw.HardwareSpec = hw.DEFAULT,
+    spec: Optional[hw.HardwareSpec] = None,
 ):
     """Analytic :class:`repro.ops.OverlapPolicy` for a layer with GLOBAL
     GEMM dims (m, k, n) sharded over ``world`` TP ranks — drop it
@@ -363,6 +366,7 @@ def recommend_overlap_modes(
     winners; the backend is the lowering recommendation
     (:func:`recommend_backend`).
     """
+    spec = spec or hw.local_spec()
     from ..ops.policy import LATENCY_OPS, OverlapPolicy
 
     ag = analytic_ag_matmul(max(1, m // world), k, max(1, n // world), world,

@@ -6,6 +6,12 @@ online softmax. Emits BOTH the un-normalized-combinable output ``o`` and
 the log-sum-exp ``lse`` so the *distributed* flash decode
 (core/flash_decode.py) can merge partials from sequence-parallel KV shards
 with the low-latency AllGather — exactly the paper's FlashDecode+AG.
+
+One grid cell serves all ``Hq / Hkv`` query heads of a KV head, so each
+K/V tile is read once per KV head. The per-slot lengths arrive by scalar
+prefetch: tiles wholly past a slot's length are neither fetched (the
+index map repeats the last live tile) nor computed. ``lse`` is written
+lane-broadcast over 128 lanes to satisfy the TPU's (8, 128) tiling.
 """
 from __future__ import annotations
 
@@ -18,6 +24,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+LANES = 128
 
 
 def _decode_kernel(
@@ -35,6 +42,7 @@ def _decode_kernel(
     bkv: int,
     kv_tiles: int,
 ):
+    length = len_ref[pl.program_id(0)]
     ikv = pl.program_id(2)
 
     @pl.when(ikv == 0)
@@ -43,32 +51,41 @@ def _decode_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32)  # (1, d) — one token
-    k = k_ref[0, 0].astype(jnp.float32)  # (bkv, d)
-    v = v_ref[0, 0].astype(jnp.float32)
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale  # (1, bkv)
-    valid = ikv * bkv + jax.lax.broadcasted_iota(jnp.int32, (1, bkv), 1) < len_ref[0]
-    s = jnp.where(valid, s, NEG_INF)
-    m_prev = m_ref[...]
-    m_cur = jnp.max(s, axis=-1, keepdims=True)
-    m_new = jnp.maximum(m_prev, jnp.broadcast_to(m_cur, m_prev.shape))
-    alpha = jnp.exp(m_prev[:, :1] - m_new[:, :1])
-    p = jnp.exp(s - m_new[:, :1])
-    l_ref[...] = l_ref[...] * alpha + jnp.broadcast_to(
-        jnp.sum(p, axis=-1, keepdims=True), l_ref.shape
-    )
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    m_ref[...] = m_new
+    @pl.when(ikv * bkv < length)
+    def _step():
+        q = q_ref[0, 0].astype(jnp.float32)  # (G, d) — the group's heads
+        k = k_ref[0, 0].astype(jnp.float32)  # (bkv, d)
+        v = v_ref[0, 0].astype(jnp.float32)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale  # (G, bkv)
+        first = ikv * bkv
+        s = jnp.where(
+            first + jax.lax.broadcasted_iota(jnp.int32, (1, bkv), 1) < length,
+            s, NEG_INF)
+        # rows past the length (or past the cache, in a ragged last tile)
+        # may hold anything: zero them so p = 0 cannot meet a NaN
+        v = jnp.where(
+            first + jax.lax.broadcasted_iota(jnp.int32, (bkv, 1), 0) < length,
+            v, 0.0)
+        m_prev = m_ref[...]  # (G, LANES), lane-broadcast
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new[:, :1])
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha[:, :1] + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        m_ref[...] = m_new
 
     @pl.when(ikv == kv_tiles - 1)
     def _done():
-        l_fin = l_ref[:, :1]
-        o_ref[0, 0] = acc_ref[...] / l_fin
-        lse_ref[0, 0, 0] = m_ref[0, 0] + jnp.log(l_fin[0, 0])
+        # a slot of length 0 (a sequence shard past the sequence's end)
+        # computed nothing: o = 0 and lse = NEG_INF, a zero weight in
+        # the distributed combine
+        l_fin = jnp.where(l_ref[...] > 0, l_ref[...], 1.0)
+        o_ref[0, 0] = acc_ref[...] / l_fin[:, :1]
+        lse_ref[0, 0] = m_ref[...] + jnp.log(l_fin)
 
 
 def flash_decode(
@@ -86,39 +103,46 @@ def flash_decode(
     _, hkv, s_len, _ = k.shape
     group = hq // hkv
     bkv = min(bkv, s_len)
-    assert s_len % bkv == 0, (s_len, bkv)
     scale = scale if scale is not None else 1.0 / float(np.sqrt(d))
-    kv_tiles = s_len // bkv
-    grid = (b, hq, kv_tiles)
+    kv_tiles = pl.cdiv(s_len, bkv)
     kernel = functools.partial(
         _decode_kernel, scale=scale, bkv=bkv, kv_tiles=kv_tiles
     )
-    q4 = q[:, :, None, :]  # (B, Hq, 1, D)
+
+    def kv_map(bb, h, ikv, lens):
+        last = (jnp.maximum(lens[bb], 1) - 1) // bkv
+        return (bb, h, jnp.minimum(ikv, last), 0)
+
+    def head_map(bb, h, ikv, lens):
+        return (bb, h, 0, 0)
+
     o, lse = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1,), lambda bb, h, ikv: (bb,), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, 1, d), lambda bb, h, ikv: (bb, h, 0, 0)),
-            pl.BlockSpec((1, 1, bkv, d), lambda bb, h, ikv: (bb, h // group, ikv, 0)),
-            pl.BlockSpec((1, 1, bkv, d), lambda bb, h, ikv: (bb, h // group, ikv, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, 1, d), lambda bb, h, ikv: (bb, h, 0, 0)),
-            pl.BlockSpec((1, 1, 1), lambda bb, h, ikv: (bb, h, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, hkv, kv_tiles),
+            in_specs=[
+                pl.BlockSpec((1, 1, group, d), head_map),
+                pl.BlockSpec((1, 1, bkv, d), kv_map),
+                pl.BlockSpec((1, 1, bkv, d), kv_map),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, 1, group, d), head_map),
+                pl.BlockSpec((1, 1, group, LANES), head_map),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((group, LANES), jnp.float32),
+                pltpu.VMEM((group, LANES), jnp.float32),
+                pltpu.VMEM((group, d), jnp.float32),
+            ],
+        ),
         out_shape=[
-            jax.ShapeDtypeStruct((b, hq, 1, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, hq, 1), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((1, 128), jnp.float32),
-            pltpu.VMEM((1, 128), jnp.float32),
-            pltpu.VMEM((1, d), jnp.float32),
+            jax.ShapeDtypeStruct((b, hkv, group, d), jnp.float32),
+            jax.ShapeDtypeStruct((b, hkv, group, LANES), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(length, q4, k, v)
-    return o[:, :, 0, :], lse[:, :, 0]
+    )(length.astype(jnp.int32), q.reshape(b, hkv, group, d), k, v)
+    return o.reshape(b, hq, d), lse[..., 0].reshape(b, hq)

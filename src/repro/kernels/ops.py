@@ -1,10 +1,11 @@
 """Public kernel entry points.
 
-Dispatch policy (production): Pallas on TPU, interpret-mode Pallas for
-kernel validation on CPU, and pure-jnp (ref.py math, XLA-fused) as the
-default CPU path so that graph-level compilation (dry-run, smoke tests)
-sees ordinary HLO. ``force="pallas"`` pins the Pallas path for the
-kernel-vs-ref test sweeps.
+Dispatch policy (production): compiled Pallas on TPU, always — neither
+interpret mode nor ref.py is ever picked there on its own. Elsewhere the
+default is pure jnp (ref.py math, XLA-fused) so that graph-level
+compilation (dry-run, smoke tests) sees ordinary HLO, and
+``force="pallas"`` runs the kernels in interpret mode for the
+kernel-vs-ref test sweeps. ``force="ref"`` pins the jnp math anywhere.
 """
 from __future__ import annotations
 
@@ -23,16 +24,20 @@ from . import ssd_scan as _ssd
 Force = Optional[Literal["pallas", "ref"]]
 
 
+def _platform() -> str:
+    """The platform the kernels are built for (a test that compiles for
+    a described chip on a CPU host steers this)."""
+    return jax.default_backend()
+
+
 def _use_pallas(force: Force) -> bool:
-    if force == "pallas":
-        return True
-    if force == "ref":
-        return False
-    return jax.default_backend() == "tpu"
+    if force is not None:
+        return force == "pallas"
+    return _platform() == "tpu"
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return _platform() != "tpu"
 
 
 def _pad_to(x: jax.Array, mult: int, axis: int) -> jax.Array:

@@ -26,7 +26,7 @@ from __future__ import annotations
 import json
 import re
 from dataclasses import asdict, dataclass
-from typing import Dict
+from typing import Dict, Optional
 
 from .. import hw
 
@@ -166,7 +166,7 @@ def analyze(
     hlo_text: str,
     loop_trips: int,
     model_flops_total: float,
-    spec: hw.HardwareSpec = hw.DEFAULT,
+    spec: Optional[hw.HardwareSpec] = None,
     links_used: int = 1,
     backward: bool = True,
 ) -> RooflineReport:
@@ -178,6 +178,7 @@ def analyze(
     unscanned head/tail is a small correction, folded into the ratio
     column rather than double-counted.
     """
+    spec = spec or hw.local_spec()
     cost = normalize_cost(cost)
     flops_dev = float(cost.get("flops", 0.0)) * loop_trips
     bytes_dev = float(cost.get("bytes accessed", 0.0)) * loop_trips

@@ -28,6 +28,8 @@ import time
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from ..configs import ARCHS, get_config, reduced
 from ..configs.base import ParallelConfig, ShapeConfig
@@ -41,6 +43,7 @@ from ..serve import (
     drive,
     generate,
 )
+from .cache import enable_compile_cache
 from .mesh import make_mesh
 from .steps import (
     build_decode_step,
@@ -60,6 +63,11 @@ def _with_policy(pcfg: ParallelConfig, policy) -> ParallelConfig:
         if f.name in ParallelConfig._LEGACY_OVERLAP_FIELDS
     }
     return dataclasses.replace(pcfg, overlap=policy, **defaults)
+
+
+def _shardings(mesh, pspecs):
+    return jax.tree.map(lambda sp: NamedSharding(mesh, sp), pspecs,
+                        is_leaf=lambda x: isinstance(x, P))
 
 
 def build_paged_engine(
@@ -110,10 +118,14 @@ def build_paged_engine(
             cfg, pre_pcfg, mesh, chunk=scfg.chunk, n_streams=dp_shards,
             num_pages=kv.num_pages, page_size=scfg.page_size,
             pages_per_slot=kv.pages_per_slot, cache_dtype=cache_dtype)
-    params, _ = dec.model.init(jax.random.PRNGKey(seed),
-                               jnp.dtype(pcfg.param_dtype))
-    pools = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
-                         dec.in_shapes[1])
+    # params and pools live on the mesh as the programs take them, so a
+    # step moves no weights between devices
+    params, pspecs = dec.model.init(jax.random.PRNGKey(seed),
+                                    jnp.dtype(pcfg.param_dtype))
+    params = jax.device_put(params, _shardings(mesh, pspecs))
+    pools = jax.tree.map(
+        lambda s, sh: jnp.zeros(s.shape, s.dtype, device=sh),
+        dec.in_shapes[1], _shardings(mesh, dec.in_pspecs[1]))
     return PagedEngine(pre.fn, dec.fn, params, pools, scfg,
                        dp_shards=dp_shards, eos_id=eos_id, seed=seed,
                        pcfg=pcfg, prefill_pcfg=pre_pcfg,
@@ -254,7 +266,9 @@ def main():
                     help="enable repro.obs tracing and write the run's "
                          "Chrome-trace JSON (kernel-backend runs record "
                          "per-PE engine events; graph runs span-label only)")
-    run(ap.parse_args())
+    args = ap.parse_args()
+    enable_compile_cache()
+    run(args)
 
 
 if __name__ == "__main__":

@@ -25,6 +25,7 @@ from ..configs.base import ParallelConfig, ShapeConfig, TrainConfig
 from ..data.pipeline import SyntheticTokens
 from ..train import optimizer as opt_mod
 from ..train.checkpoint import Checkpointer
+from .cache import enable_compile_cache
 from .mesh import make_mesh
 from .steps import build_train_step, batch_spec
 
@@ -129,7 +130,9 @@ def main():
     ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
     ap.add_argument("--ckpt-every", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=5)
-    run(ap.parse_args())
+    args = ap.parse_args()
+    enable_compile_cache()
+    run(args)
 
 
 if __name__ == "__main__":

@@ -119,6 +119,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Callable, Optional, Sequence
 
 import jax
@@ -127,6 +128,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import hw
 from . import default_backend, tpu_backend
 from . import emulated as em
 
@@ -698,6 +700,19 @@ def _push_rs_ring_ag_emulated(chain, operand, statics, *, axis, world,
 # ---------------------------------------------------------------------------
 
 
+def _compiler_params(cid: int, scratch) -> pltpu.CompilerParams:
+    """CompilerParams for a protocol kernel, with a scoped-VMEM limit
+    sized from its VMEM footprint: the scratch buffers, as much again
+    for the tile compute's values (its f32 product lives beside the
+    buffers before the cast into the output tile), and 4 MiB for the
+    compiler's own use, capped at the chip's VMEM. Whole-shard weights
+    at real widths do not fit the default scoped limit."""
+    vmem = sum(math.prod(s.shape) * jnp.dtype(s.dtype).itemsize
+               for s in scratch if hasattr(s, "dtype"))
+    limit = min(2 * vmem + (4 << 20), hw.local_spec().vmem_bytes)
+    return pltpu.CompilerParams(collective_id=cid, vmem_limit_bytes=limit)
+
+
 def _stage(refs, vmems, sem):
     copies = [pltpu.make_async_copy(r, v, sem) for r, v in zip(refs, vmems)]
     for c in copies:
@@ -768,6 +783,13 @@ def _ring_ag_pltpu(tile, chunk, statics, *, axis, world, out_dtype, cid):
     body = functools.partial(
         _ring_ag_body, tile=tile, axis=axis, world=world,
         n_static=len(statics), tile_m=ts.shape[0], out_dtype=out_dtype)
+    scratch = ([pltpu.VMEM(chunk.shape, chunk.dtype)]
+               + [pltpu.VMEM(s.shape, s.dtype) for s in statics]
+               + [pltpu.VMEM(ts.shape, out_dtype),
+                  pltpu.SemaphoreType.DMA,
+                  pltpu.SemaphoreType.DMA,
+                  pltpu.SemaphoreType.DMA,
+                  pltpu.SemaphoreType.REGULAR])
     out, _ws = pl.pallas_call(
         body,
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * (1 + len(statics)),
@@ -776,14 +798,8 @@ def _ring_ag_pltpu(tile, chunk, statics, *, axis, world, out_dtype, cid):
             jax.ShapeDtypeStruct((ts.shape[0] * world,) + ts.shape[1:], out_dtype),
             jax.ShapeDtypeStruct((2,) + chunk.shape, chunk.dtype),  # ring ws
         ],
-        scratch_shapes=[pltpu.VMEM(chunk.shape, chunk.dtype)]
-        + [pltpu.VMEM(s.shape, s.dtype) for s in statics]
-        + [pltpu.VMEM(ts.shape, out_dtype),
-           pltpu.SemaphoreType.DMA,
-           pltpu.SemaphoreType.DMA,
-           pltpu.SemaphoreType.DMA,
-           pltpu.SemaphoreType.REGULAR],
-        compiler_params=pltpu.CompilerParams(collective_id=cid),
+        scratch_shapes=scratch,
+        compiler_params=_compiler_params(cid, scratch),
     )(chunk, *statics)
     return out
 
@@ -858,7 +874,7 @@ def _one_shot_ag_pltpu(tile, chunk, statics, *, axis, world, out_dtype, cid):
         out_specs=[pl.BlockSpec(memory_space=pl.ANY)] * len(out_shape),
         out_shape=out_shape,
         scratch_shapes=scratch,
-        compiler_params=pltpu.CompilerParams(collective_id=cid),
+        compiler_params=_compiler_params(cid, scratch),
     )(chunk, *statics)
     return outs[0] if isinstance(outs, (tuple, list)) else outs
 
@@ -977,7 +993,7 @@ def _rs_pltpu(tile, operand, statics, *, axis, world, out_dtype, cid,
         out_specs=[pl.BlockSpec(memory_space=pl.ANY)] * len(out_shape),
         out_shape=out_shape,
         scratch_shapes=scratch,
-        compiler_params=pltpu.CompilerParams(collective_id=cid),
+        compiler_params=_compiler_params(cid, scratch),
     )(operand, *statics)
     return outs[0]
 
@@ -1063,6 +1079,16 @@ def _bidir_ring_ag_pltpu(tile, chunk, statics, *, axis, world, out_dtype, cid):
         _bidir_ring_ag_body, tile=tile, axis=axis, world=world,
         n_static=len(statics), half_rows=half_rows, tile_h=tile_h,
         out_dtype=out_dtype)
+    scratch = ([pltpu.VMEM(half_struct.shape, chunk.dtype)]
+               + [pltpu.VMEM(s.shape, s.dtype) for s in statics]
+               + [pltpu.VMEM(ts.shape, out_dtype),
+                  pltpu.SemaphoreType.DMA,   # local staging
+                  pltpu.SemaphoreType.DMA,   # fwd send
+                  pltpu.SemaphoreType.DMA,   # fwd recv
+                  pltpu.SemaphoreType.DMA,   # bwd send
+                  pltpu.SemaphoreType.DMA,   # bwd recv
+                  pltpu.SemaphoreType.REGULAR,   # fwd credits
+                  pltpu.SemaphoreType.REGULAR])  # bwd credits
     out, _wsf, _wsb = pl.pallas_call(
         body,
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * (1 + len(statics)),
@@ -1073,17 +1099,8 @@ def _bidir_ring_ag_pltpu(tile, chunk, statics, *, axis, world, out_dtype, cid):
             jax.ShapeDtypeStruct((2,) + half_struct.shape, chunk.dtype),
             jax.ShapeDtypeStruct((2,) + half_struct.shape, chunk.dtype),
         ],
-        scratch_shapes=[pltpu.VMEM(half_struct.shape, chunk.dtype)]
-        + [pltpu.VMEM(s.shape, s.dtype) for s in statics]
-        + [pltpu.VMEM(ts.shape, out_dtype),
-           pltpu.SemaphoreType.DMA,   # local staging
-           pltpu.SemaphoreType.DMA,   # fwd send
-           pltpu.SemaphoreType.DMA,   # fwd recv
-           pltpu.SemaphoreType.DMA,   # bwd send
-           pltpu.SemaphoreType.DMA,   # bwd recv
-           pltpu.SemaphoreType.REGULAR,   # fwd credits
-           pltpu.SemaphoreType.REGULAR],  # bwd credits
-        compiler_params=pltpu.CompilerParams(collective_id=cid),
+        scratch_shapes=scratch,
+        compiler_params=_compiler_params(cid, scratch),
     )(chunk, *statics)
     return out
 
@@ -1158,7 +1175,7 @@ def _one_shot_a2a_pltpu(tile, xs, statics, *, axis, world, out_dtype, cid):
         out_specs=[pl.BlockSpec(memory_space=pl.ANY)] * len(out_shape),
         out_shape=out_shape,
         scratch_shapes=scratch,
-        compiler_params=pltpu.CompilerParams(collective_id=cid),
+        compiler_params=_compiler_params(cid, scratch),
     )(xs, *statics)
     return outs[0] if isinstance(outs, (tuple, list)) else outs
 
@@ -1236,6 +1253,14 @@ def _ring_fold_pltpu(fold, chunk, statics, *, axis, world, out_dtype, cid):
         _ring_fold_body, fold=fold, axis=axis, world=world,
         n_static=len(statics), n_state=len(state_leaves),
         state_treedef=state_treedef, out_dtype=out_dtype)
+    scratch = ([pltpu.VMEM(chunk.shape, chunk.dtype)]
+               + [pltpu.VMEM(s.shape, s.dtype) for s in statics]
+               + [pltpu.VMEM(leaf.shape, leaf.dtype) for leaf in state_leaves]
+               + [pltpu.VMEM(out_struct.shape, out_dtype),
+                  pltpu.SemaphoreType.DMA,
+                  pltpu.SemaphoreType.DMA,
+                  pltpu.SemaphoreType.DMA,
+                  pltpu.SemaphoreType.REGULAR])
     out, _ws = pl.pallas_call(
         body,
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * (1 + len(statics)),
@@ -1244,15 +1269,8 @@ def _ring_fold_pltpu(fold, chunk, statics, *, axis, world, out_dtype, cid):
             jax.ShapeDtypeStruct(out_struct.shape, out_dtype),
             jax.ShapeDtypeStruct((2,) + chunk.shape, chunk.dtype),  # ring ws
         ],
-        scratch_shapes=[pltpu.VMEM(chunk.shape, chunk.dtype)]
-        + [pltpu.VMEM(s.shape, s.dtype) for s in statics]
-        + [pltpu.VMEM(leaf.shape, leaf.dtype) for leaf in state_leaves]
-        + [pltpu.VMEM(out_struct.shape, out_dtype),
-           pltpu.SemaphoreType.DMA,
-           pltpu.SemaphoreType.DMA,
-           pltpu.SemaphoreType.DMA,
-           pltpu.SemaphoreType.REGULAR],
-        compiler_params=pltpu.CompilerParams(collective_id=cid),
+        scratch_shapes=scratch,
+        compiler_params=_compiler_params(cid, scratch),
     )(chunk, *statics)
     return out
 
@@ -1327,6 +1345,15 @@ def _two_level_ag_pltpu(tile, chunk, statics, *, axis, world, out_dtype, cid):
     body = functools.partial(
         _two_level_ag_body, tile=tile, axes=(outer, inner), worlds=(wo, wi),
         n_static=len(statics), tile_m=ts.shape[0], out_dtype=out_dtype)
+    scratch = ([pltpu.VMEM(chunk.shape, chunk.dtype)]
+               + [pltpu.VMEM(s.shape, s.dtype) for s in statics]
+               + [pltpu.VMEM(ts.shape, out_dtype),
+                  pltpu.SemaphoreType.DMA,   # local staging
+                  pltpu.SemaphoreType.DMA,   # pod send
+                  pltpu.SemaphoreType.DMA,   # pod recv
+                  pltpu.SemaphoreType.DMA,   # outer send
+                  pltpu.SemaphoreType.DMA,   # outer recv
+                  pltpu.SemaphoreType.REGULAR])  # outer credits
     out, _pws, _ows = pl.pallas_call(
         body,
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * (1 + len(statics)),
@@ -1337,16 +1364,8 @@ def _two_level_ag_pltpu(tile, chunk, statics, *, axis, world, out_dtype, cid):
             jax.ShapeDtypeStruct((2 * wi,) + chunk.shape, chunk.dtype),  # pod
             jax.ShapeDtypeStruct((2,) + chunk.shape, chunk.dtype),  # outer
         ],
-        scratch_shapes=[pltpu.VMEM(chunk.shape, chunk.dtype)]
-        + [pltpu.VMEM(s.shape, s.dtype) for s in statics]
-        + [pltpu.VMEM(ts.shape, out_dtype),
-           pltpu.SemaphoreType.DMA,   # local staging
-           pltpu.SemaphoreType.DMA,   # pod send
-           pltpu.SemaphoreType.DMA,   # pod recv
-           pltpu.SemaphoreType.DMA,   # outer send
-           pltpu.SemaphoreType.DMA,   # outer recv
-           pltpu.SemaphoreType.REGULAR],  # outer credits
-        compiler_params=pltpu.CompilerParams(collective_id=cid),
+        scratch_shapes=scratch,
+        compiler_params=_compiler_params(cid, scratch),
     )(chunk, *statics)
     return out
 
@@ -1434,6 +1453,17 @@ def _two_level_rs_pltpu(tile, operand, statics, *, axis, world, out_dtype,
     body = functools.partial(
         _two_level_rs_body, tile=tile, axes=(outer, inner), worlds=(wo, wi),
         n_static=len(statics), m_blk=m_blk, out_dtype=out_dtype)
+    scratch = ([pltpu.VMEM(blk_struct.shape, operand.dtype)]
+               + [pltpu.VMEM(s.shape, s.dtype) for s in statics]
+               + [pltpu.VMEM(ts.shape, jnp.float32),
+                  pltpu.VMEM(ts.shape, jnp.float32),
+                  pltpu.VMEM(ts.shape, out_dtype),
+                  pltpu.SemaphoreType.DMA,   # local staging
+                  pltpu.SemaphoreType.DMA,   # pod send
+                  pltpu.SemaphoreType.DMA,   # pod recv
+                  pltpu.SemaphoreType.DMA,   # outer send
+                  pltpu.SemaphoreType.DMA,   # outer recv
+                  pltpu.SemaphoreType.REGULAR])  # outer credits
     outs = pl.pallas_call(
         body,
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * (1 + len(statics)),
@@ -1444,18 +1474,8 @@ def _two_level_rs_pltpu(tile, operand, statics, *, axis, world, out_dtype,
             jax.ShapeDtypeStruct((2,) + ts.shape, jnp.float32),  # outer
             jax.ShapeDtypeStruct((wi,) + ts.shape, jnp.float32),  # staging
         ],
-        scratch_shapes=[pltpu.VMEM(blk_struct.shape, operand.dtype)]
-        + [pltpu.VMEM(s.shape, s.dtype) for s in statics]
-        + [pltpu.VMEM(ts.shape, jnp.float32),
-           pltpu.VMEM(ts.shape, jnp.float32),
-           pltpu.VMEM(ts.shape, out_dtype),
-           pltpu.SemaphoreType.DMA,   # local staging
-           pltpu.SemaphoreType.DMA,   # pod send
-           pltpu.SemaphoreType.DMA,   # pod recv
-           pltpu.SemaphoreType.DMA,   # outer send
-           pltpu.SemaphoreType.DMA,   # outer recv
-           pltpu.SemaphoreType.REGULAR],  # outer credits
-        compiler_params=pltpu.CompilerParams(collective_id=cid),
+        scratch_shapes=scratch,
+        compiler_params=_compiler_params(cid, scratch),
     )(operand, *statics)
     return outs[0]
 
@@ -1566,6 +1586,17 @@ def _push_rs_ring_ag_pltpu(chain, operand, statics, *, axis, world, out_dtype,
         _push_rs_ring_ag_body, chain=chain, axis=axis, world=world,
         n_rs=n_rs, n_ag=n_ag, n_mid=len(mid_statics), m_blk=m_blk,
         tile_m=ag_ts.shape[0], out_dtype=out_dtype, h_dtype=h_struct.dtype)
+    scratch = ([pltpu.VMEM(blk_struct.shape, operand.dtype)]
+               + [pltpu.VMEM(s.shape, s.dtype) for s in statics]
+               + [pltpu.VMEM(rs_ts.shape, jnp.float32),
+                  pltpu.VMEM(h_struct.shape, h_struct.dtype),
+                  pltpu.VMEM(ag_ts.shape, out_dtype),
+                  pltpu.SemaphoreType.DMA,   # local staging
+                  pltpu.SemaphoreType.DMA,   # rs send
+                  pltpu.SemaphoreType.DMA,   # rs recv
+                  pltpu.SemaphoreType.DMA,   # ag send
+                  pltpu.SemaphoreType.DMA,   # ag recv
+                  pltpu.SemaphoreType.REGULAR])  # ag credits
     out, _wsr, _wsa = pl.pallas_call(
         body,
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * (1 + len(statics)),
@@ -1576,18 +1607,8 @@ def _push_rs_ring_ag_pltpu(chain, operand, statics, *, axis, world, out_dtype,
             jax.ShapeDtypeStruct((world,) + rs_ts.shape, jnp.float32),  # rs ws
             jax.ShapeDtypeStruct((2,) + h_struct.shape, h_struct.dtype),  # ag
         ],
-        scratch_shapes=[pltpu.VMEM(blk_struct.shape, operand.dtype)]
-        + [pltpu.VMEM(s.shape, s.dtype) for s in statics]
-        + [pltpu.VMEM(rs_ts.shape, jnp.float32),
-           pltpu.VMEM(h_struct.shape, h_struct.dtype),
-           pltpu.VMEM(ag_ts.shape, out_dtype),
-           pltpu.SemaphoreType.DMA,   # local staging
-           pltpu.SemaphoreType.DMA,   # rs send
-           pltpu.SemaphoreType.DMA,   # rs recv
-           pltpu.SemaphoreType.DMA,   # ag send
-           pltpu.SemaphoreType.DMA,   # ag recv
-           pltpu.SemaphoreType.REGULAR],  # ag credits
-        compiler_params=pltpu.CompilerParams(collective_id=cid),
+        scratch_shapes=scratch,
+        compiler_params=_compiler_params(cid, scratch),
     )(operand, *statics)
     return out
 

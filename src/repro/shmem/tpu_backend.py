@@ -21,8 +21,6 @@ from typing import Optional
 from jax import lax
 from jax.experimental.pallas import tpu as pltpu
 
-from .. import _compat  # noqa: F401  (pltpu name backfills)
-
 
 def annotate(kind: str, name: str = ""):
     """The pltpu mapping of :mod:`repro.obs` span labels: a

@@ -95,6 +95,42 @@ def test_flash_decode_sweep(s, hq, hkv):
     np.testing.assert_allclose(np.asarray(lg), np.asarray(lw), atol=2e-5, rtol=2e-5)
 
 
+@pytest.mark.parametrize("s,bkv,lens", [(80, 32, (80, 33)), (48, 32, (1, 17)),
+                                        (96, 32, (5, 96))])
+def test_flash_decode_ragged_tiles(s, bkv, lens):
+    """A cache that is not a multiple of the KV tile (page geometries
+    that do not divide it), and slots whose length ends tiles early."""
+    b, hq, hkv, d = 2, 4, 2, 32
+    q = _arr((b, hq, d))
+    k = _arr((b, hkv, s, d))
+    v = _arr((b, hkv, s, d))
+    lens = jnp.asarray(lens, jnp.int32)
+    og, lg = ops.flash_decode(q, k, v, lens, force="pallas", bkv=bkv)
+    ow, lw = ref.flash_decode(q, k, v, length=lens)
+    np.testing.assert_allclose(np.asarray(og), np.asarray(ow), atol=2e-5, rtol=2e-4)
+    np.testing.assert_allclose(np.asarray(lg), np.asarray(lw), atol=2e-5, rtol=2e-5)
+
+
+def test_flash_decode_empty_shard_drops_out_of_combine():
+    """Sequence-parallel shards: a slot whose sequence ends before the
+    second shard gives that shard length 0. Its partial must weigh
+    nothing in the combine, so the merged output equals the whole-cache
+    reference."""
+    b, hq, hkv, s, d = 2, 4, 2, 64, 32
+    q = _arr((b, hq, d))
+    k = _arr((b, hkv, s, d))
+    v = _arr((b, hkv, s, d))
+    lens = np.asarray([20, 50], np.int32)
+    parts = [ops.flash_decode(q, k[:, :, lo:lo + 32], v[:, :, lo:lo + 32],
+                              jnp.asarray(np.clip(lens - lo, 0, 32)),
+                              force="pallas", bkv=16)
+             for lo in (0, 32)]
+    got = ops.combine_flash_decode(jnp.stack([o for o, _ in parts]),
+                                   jnp.stack([lse for _, lse in parts]))
+    want, _ = ref.flash_decode(q, k, v, length=jnp.asarray(lens))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=2e-4)
+
+
 # --------------------------------------------------------------- ssd scan
 @pt.given(examples=6, l=pt.sampled_from([32, 64]), h=pt.sampled_from([2, 4]),
           g=pt.sampled_from([1, 2]), chunk=pt.sampled_from([8, 16, 32]))

@@ -269,9 +269,9 @@ def test_policy_single_resolution_point():
     assert pol.resolve("ag_matmul_2level") == ops.ResolvedOverlap(
         "two_level", "kernel", 2)
     # hw-aware degrade: no ICI links -> no remote-DMA engine -> graph
-    no_ici = dataclasses.replace(hw.DEFAULT, ici_links=0)
+    no_ici = dataclasses.replace(hw.TARGET, ici_links=0)
     assert pol.resolve("ag_matmul", hw=no_ici).backend == "graph"
-    assert pol.resolve("ag_matmul", hw=hw.DEFAULT).backend == "kernel"
+    assert pol.resolve("ag_matmul", hw=hw.TARGET).backend == "kernel"
     # dict ergonomics + describe
     pol2 = ops.OverlapPolicy(modes={"ag_matmul": "one_shot"})
     assert pol2.mode_for("ag_matmul") == "one_shot"

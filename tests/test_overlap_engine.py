@@ -574,44 +574,34 @@ _SCAN_KERNEL_TRAIN = textwrap.dedent("""
     A = jnp.asarray(rng.randn(4 * W, 8), jnp.float32)
     Wt = jnp.asarray(rng.randn(8, 2 * W), jnp.float32)
 
-    def loss(a, w):
-        # a 2-"layer" scan over the kernel-backend op: the whole-model
+    def loss(a, w, backend):
+        # a 2-"layer" scan over the overlapped op: the whole-model
         # training shape (layers scanned, overlapped op inside)
         def layer(carry, _):
             y = ops.ag_matmul(carry, w, axis="tp", mode="ring",
-                              backend="kernel", out_dtype=jnp.float32)
+                              backend=backend, out_dtype=jnp.float32)
             return carry, jnp.sum(y * y)
         _, ys = lax.scan(layer, a, jnp.arange(2))
         return lax.psum(jnp.sum(ys), "tp")
 
-    g = jax.jit(jax.shard_map(jax.grad(loss, argnums=(0, 1)), mesh=mesh,
-                              in_specs=(P("tp", None), P(None, "tp")),
-                              out_specs=(P("tp", None), P(None, "tp")),
-                              check_vma=False))(A, Wt)
-    jax.block_until_ready(g)
+    def grads(backend):
+        g = jax.jit(jax.shard_map(
+            jax.grad(functools.partial(loss, backend=backend),
+                     argnums=(0, 1)),
+            mesh=mesh, in_specs=(P("tp", None), P(None, "tp")),
+            out_specs=(P("tp", None), P(None, "tp")),
+            check_vma=False))(A, Wt)
+        return [np.asarray(x) for x in g]
+
+    for gk, gg in zip(grads("kernel"), grads("graph")):
+        np.testing.assert_array_equal(gk, gg)
     print("OK scan kernel train")
 """)
 
 
-@pytest.mark.xfail(
-    strict=True, raises=RuntimeError,
-    reason="jax CPU-emulation limit: io_callback effects inside the shared "
-           "custom_vjp are rejected under lax.scan ('Effects not supported "
-           "in custom_vjp'); the pltpu lowering carries no IOEffect, so "
-           "this is emulated-backend-only. A jax-side fix flips this "
-           "loudly (strict XPASS).")
-def test_kernel_backend_training_under_scan_hits_custom_vjp_effects_limit():
-    """Kernel-backend TRAINING under ``lax.scan`` on CPU: pins the exact
-    known-failure message so the emulation limit is visible. Any other
-    failure mode is a REAL failure (the AssertionError is re-raised and
-    not matched by ``raises=RuntimeError``)."""
-    try:
-        out = run_devices(_SCAN_KERNEL_TRAIN, devices=2)
-    except AssertionError as e:
-        # jax spells it "Effects not supported in `custom_vjp`"
-        if "Effects not supported in" in str(e) and "custom_vjp" in str(e):
-            raise RuntimeError(
-                "known jax limit: Effects not supported in custom_vjp"
-            ) from e
-        raise
+def test_kernel_backend_training_under_scan_matches_graph():
+    """Kernel-backend TRAINING under ``lax.scan`` (the emulated
+    backend's io_callback effects inside the shared custom_vjp, layers
+    scanned): the grads equal the graph backend's bit for bit."""
+    out = run_devices(_SCAN_KERNEL_TRAIN, devices=2)
     assert "OK scan kernel train" in out

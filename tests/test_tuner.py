@@ -1,6 +1,8 @@
 """Autotuner tests: analytic model sanity + the paper's whole-step
 empirical protocol (§3.8)."""
+import jax
 import jax.numpy as jnp
+import pytest
 
 from repro.core import tuner
 from repro import hw
@@ -134,6 +136,22 @@ def test_analytic_respects_link_bandwidth():
     c_slow = tuner.analytic_ag_matmul(1024, 4096, 4096, 16, spec=slow)
     c_fast = tuner.analytic_ag_matmul(1024, 4096, 4096, 16, spec=fast)
     assert c_slow.t_total > c_fast.t_total
+
+
+def test_peaks_keyed_by_device_kind(monkeypatch):
+    """Peaks come from the device kind JAX reports; an unknown chip is an
+    error, not v5e's numbers. A CPU host plans for the target chip."""
+    assert hw.spec_for("TPU v5 lite") is hw.TPU_V5E
+    with pytest.raises(ValueError, match="no peak rates"):
+        hw.spec_for("TPU v99")
+    assert hw.local_spec() is hw.TARGET
+
+    class Chip:
+        platform, device_kind = "tpu", "TPU v99"
+
+    monkeypatch.setattr(jax, "devices", lambda *a: [Chip()])
+    with pytest.raises(ValueError, match="TPU v99"):
+        tuner.recommend_overlap_modes(4096, 2048, 8192, 4)
 
 
 def test_empirical_tuner_whole_step_protocol():

@@ -167,7 +167,7 @@ PARITY = textwrap.dedent("""
 
     def sh(fn, in_specs, out_specs):
         return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                     out_specs=out_specs, check_rep=False))
+                                     out_specs=out_specs, check_vma=False))
 
     def rel(a, b):
         a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
@@ -299,7 +299,7 @@ GRADS = textwrap.dedent("""
                 return lax.psum(jnp.sum(out), "tp")
             return jax.jit(jax.shard_map(
                 jax.grad(f, argnums=(0, 1)), mesh=mesh,
-                in_specs=in_specs, out_specs=in_specs, check_rep=False))
+                in_specs=in_specs, out_specs=in_specs, check_vma=False))
 
         g_f32 = make_grad("graph", "f32")(A, Wt)
         g_g = make_grad("graph", "int8")(A, Wt)
@@ -341,7 +341,7 @@ EF = textwrap.dedent("""
         functools.partial(compress.pod_allreduce_int8, axis="pod"),
         mesh=mesh, in_specs=(P("pod", None, None), P("pod", None, None)),
         out_specs=(P("pod", None, None), P("pod", None, None)),
-        check_rep=False))
+        check_vma=False))
 
     def run(feedback, steps=8):
         ef = jnp.zeros_like(G)
