@@ -1,0 +1,7 @@
+"""Host time per engine step in which the device had nothing to run, in
+ms (readers.host_ms); every step waits for it."""
+from bench import readers
+
+
+def read(ctx):
+    return readers.host_ms(ctx)
