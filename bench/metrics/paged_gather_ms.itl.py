@@ -1,0 +1,8 @@
+"""Device time per decode run under the ``paged_gather`` scope (the
+block tables' pages gathered into per-slot K/V, every layer), in ms;
+every gap between tokens holds one decode run."""
+from bench import scopes
+
+
+def read(ctx):
+    return scopes.scope_ms(ctx, "decode", r"/paged_gather/")

@@ -260,13 +260,13 @@ def build_paged_decode_step(
     gb = shape.global_batch
     vec_spec = batch_spec(gb, pcfg, extra_dims=0)
 
-    def fn(params, pools, table, lengths, active, token):
+    def paged_decode_step(params, pools, table, lengths, active, token):
         return model.decode_step_paged_local(
             params, pools, table, lengths, active, token)
 
     jitted = _shard(
         mesh,
-        fn,
+        paged_decode_step,
         (pspec, p_specs, batch_spec(gb, pcfg), vec_spec, vec_spec,
          batch_spec(gb, pcfg)),
         (batch_spec(gb, pcfg), p_specs),
@@ -301,13 +301,13 @@ def build_prefill_chunk_step(
     bspec = batch_spec(n_streams, pcfg)
     vec_spec = batch_spec(n_streams, pcfg, extra_dims=0)
 
-    def fn(params, pools, table_rows, starts, n_valids, tokens):
+    def prefill_chunk_step(params, pools, table_rows, starts, n_valids, tokens):
         return model.prefill_chunk_local(
             params, pools, table_rows, starts, n_valids, tokens)
 
     jitted = _shard(
         mesh,
-        fn,
+        prefill_chunk_step,
         (pspec, p_specs, bspec, vec_spec, vec_spec, bspec),
         (bspec, p_specs),
         donate=(1,),
@@ -343,14 +343,15 @@ def build_prefill_chunk_cp_step(
     p_shapes, p_specs = _pool_specs(model, num_pages, page_size, cache_dtype)
     rep1, rep2 = P(None), P(None, None)
 
-    def fn(params, pools, table_rows, starts, n_valids, tokens):
+    def prefill_chunk_cp_step(params, pools, table_rows, starts, n_valids,
+                              tokens):
         return model.prefill_chunk_cp_local(
             params, pools, table_rows, starts, n_valids, tokens,
             placement=placement, cp_attend=cp_attend)
 
     jitted = _shard(
         mesh,
-        fn,
+        prefill_chunk_cp_step,
         (pspec, p_specs, rep2, rep1, rep1, rep2),
         (rep2, p_specs),
         donate=(1,),

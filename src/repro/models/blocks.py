@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .. import obs
 from .. import ops as oplib
 from ..configs.base import ModelConfig, ParallelConfig
 from ..core import collective_matmul as cm
@@ -326,8 +327,9 @@ def _gather_pages(pool: Array, table: Array) -> Array:
     """
     _, h, ps, hd = pool.shape
     b, pcount = table.shape
-    g = pool[table]  # (B, P, H, ps, hd)
-    return g.transpose(0, 2, 1, 3, 4).reshape(b, h, pcount * ps, hd)
+    with obs.scope("paged_gather"):
+        g = pool[table]  # (B, P, H, ps, hd)
+        return g.transpose(0, 2, 1, 3, 4).reshape(b, h, pcount * ps, hd)
 
 
 def attention_decode_paged(
@@ -349,26 +351,31 @@ def attention_decode_paged(
     hd = cfg.head_dim
     ps = pool_k.shape[2]
     pp = _get_attn(p, x.dtype)
-    h = rmsnorm(x, pp.ln, cfg.norm_eps).reshape(b, d)
-    q = local_linear(h, pp.wq, pp.bq).reshape(b, info.hq_loc, hd)
-    kv = local_linear(h, pp.wkv, pp.bkv).reshape(b, 2, info.hkv_loc, hd)
-    k_new, v_new = kv[:, 0], kv[:, 1]
-    pos = lengths.astype(jnp.int32)
-    if cfg.use_rope:
-        q = rope(q[:, None], pos[:, None], cfg.rope_theta)[:, 0]
-        k_new = rope(k_new[:, None], pos[:, None], cfg.rope_theta)[:, 0]
-    rows = jnp.arange(b)
-    page = jnp.where(active, table[rows, pos // ps], 0)
-    off = pos % ps
-    pool_k = pool_k.at[page, :, off, :].set(k_new.astype(pool_k.dtype))
-    pool_v = pool_v.at[page, :, off, :].set(v_new.astype(pool_v.dtype))
-    k_all = _gather_pages(pool_k, table)
-    v_all = _gather_pages(pool_v, table)
-    eff = jnp.where(active, pos + 1, 1)
-    o, _ = ops.flash_decode(q, k_all, v_all, eff)
-    o = o.astype(x.dtype).reshape(b, info.hq_loc * hd)
-    out = psum_tp(local_linear(o, pp.wo), pcfg)
-    return x + out.reshape(b, 1, d), pool_k, pool_v
+    with obs.scope("attn"):
+        with obs.scope("qkv"):
+            h = rmsnorm(x, pp.ln, cfg.norm_eps).reshape(b, d)
+            q = local_linear(h, pp.wq, pp.bq).reshape(b, info.hq_loc, hd)
+            kv = local_linear(h, pp.wkv, pp.bkv).reshape(b, 2, info.hkv_loc, hd)
+            k_new, v_new = kv[:, 0], kv[:, 1]
+            pos = lengths.astype(jnp.int32)
+            if cfg.use_rope:
+                q = rope(q[:, None], pos[:, None], cfg.rope_theta)[:, 0]
+                k_new = rope(k_new[:, None], pos[:, None], cfg.rope_theta)[:, 0]
+        with obs.scope("kv_write"):
+            rows = jnp.arange(b)
+            page = jnp.where(active, table[rows, pos // ps], 0)
+            off = pos % ps
+            pool_k = pool_k.at[page, :, off, :].set(k_new.astype(pool_k.dtype))
+            pool_v = pool_v.at[page, :, off, :].set(v_new.astype(pool_v.dtype))
+        k_all = _gather_pages(pool_k, table)
+        v_all = _gather_pages(pool_v, table)
+        with obs.scope("flash_decode"):
+            eff = jnp.where(active, pos + 1, 1)
+            o, _ = ops.flash_decode(q, k_all, v_all, eff)
+        with obs.scope("out"):
+            o = o.astype(x.dtype).reshape(b, info.hq_loc * hd)
+            out = psum_tp(local_linear(o, pp.wo), pcfg)
+            return x + out.reshape(b, 1, d), pool_k, pool_v
 
 
 def _chunk_attend(q: Array, k_all: Array, v_all: Array, qpos: Array,
@@ -415,36 +422,41 @@ def attention_prefill_chunk(
     ps = pool_k.shape[2]
     pp = _get_attn(p, x_sp.dtype)
 
-    h = rmsnorm(x_sp, pp.ln, cfg.norm_eps).reshape(b * s_loc, d)
-    wqkv = jnp.concatenate([pp.wq, pp.wkv], axis=1)
-    bqkv = jnp.concatenate([pp.bq, pp.bkv]) if pp.bq is not None else None
-    y = ag_linear(h, wqkv, pcfg, bqkv)  # (tp*B*S_loc, cols)
-    y = _sp_gathered_to_bsd(y, tp, b, s_loc)  # (1, C, cols)
-    q, kv = jnp.split(y, [info.hq_loc * hd], axis=-1)
-    k, v = jnp.split(kv, 2, axis=-1)
-    q = q.reshape(b, c, info.hq_loc, hd)
-    k = k.reshape(b, c, info.hkv_loc, hd)
-    v = v.reshape(b, c, info.hkv_loc, hd)
-    pos = start + jnp.arange(c)
-    if cfg.use_rope:
-        q = rope(q, pos, cfg.rope_theta)
-        k = rope(k, pos, cfg.rope_theta)
+    with obs.scope("attn"):
+        with obs.scope("qkv"):
+            h = rmsnorm(x_sp, pp.ln, cfg.norm_eps).reshape(b * s_loc, d)
+            wqkv = jnp.concatenate([pp.wq, pp.wkv], axis=1)
+            bqkv = jnp.concatenate([pp.bq, pp.bkv]) if pp.bq is not None else None
+            y = ag_linear(h, wqkv, pcfg, bqkv)  # (tp*B*S_loc, cols)
+            y = _sp_gathered_to_bsd(y, tp, b, s_loc)  # (1, C, cols)
+            q, kv = jnp.split(y, [info.hq_loc * hd], axis=-1)
+            k, v = jnp.split(kv, 2, axis=-1)
+            q = q.reshape(b, c, info.hq_loc, hd)
+            k = k.reshape(b, c, info.hkv_loc, hd)
+            v = v.reshape(b, c, info.hkv_loc, hd)
+            pos = start + jnp.arange(c)
+            if cfg.use_rope:
+                q = rope(q, pos, cfg.rope_theta)
+                k = rope(k, pos, cfg.rope_theta)
 
-    valid = jnp.arange(c) < n_valid
-    pages = jnp.where(valid, table_row[pos // ps], 0)
-    offs = pos % ps
-    pool_k = pool_k.at[pages, :, offs, :].set(k[0].astype(pool_k.dtype))
-    pool_v = pool_v.at[pages, :, offs, :].set(v[0].astype(pool_v.dtype))
+        with obs.scope("kv_write"):
+            valid = jnp.arange(c) < n_valid
+            pages = jnp.where(valid, table_row[pos // ps], 0)
+            offs = pos % ps
+            pool_k = pool_k.at[pages, :, offs, :].set(k[0].astype(pool_k.dtype))
+            pool_v = pool_v.at[pages, :, offs, :].set(v[0].astype(pool_v.dtype))
 
-    k_all = _gather_pages(pool_k, table_row[None, :])
-    v_all = _gather_pages(pool_v, table_row[None, :])
-    # all-masked rows would NaN; an idle shard (n_valid == 0) attends one
-    # scratch position instead, and its output is discarded by the caller
-    limit = start + jnp.maximum(n_valid, 1)
-    o = _chunk_attend(q, k_all, v_all, pos, limit)
-    o = o.astype(x_sp.dtype).reshape(b, c, info.hq_loc * hd)
-    out = rs_linear(_bsd_to_sp_rows(o, tp), pp.wo, pcfg)
-    return x_sp + out.reshape(b, s_loc, d), pool_k, pool_v
+        k_all = _gather_pages(pool_k, table_row[None, :])
+        v_all = _gather_pages(pool_v, table_row[None, :])
+        with obs.scope("chunk_attend"):
+            # all-masked rows would NaN; an idle shard (n_valid == 0) attends
+            # one scratch position instead, and the caller discards its output
+            limit = start + jnp.maximum(n_valid, 1)
+            o = _chunk_attend(q, k_all, v_all, pos, limit)
+        with obs.scope("out"):
+            o = o.astype(x_sp.dtype).reshape(b, c, info.hq_loc * hd)
+            out = rs_linear(_bsd_to_sp_rows(o, tp), pp.wo, pcfg)
+            return x_sp + out.reshape(b, s_loc, d), pool_k, pool_v
 
 
 def _prefix_partial(q: Array, k_all: Array, v_all: Array, start: Array):
@@ -585,22 +597,24 @@ def _mlp_act(cfg, y: Array) -> Array:
 
 
 def mlp_train(cfg, pcfg, info, p: dict, x_sp: Array) -> Array:
-    b, s_loc, d = x_sp.shape
-    dt = x_sp.dtype
-    h = rmsnorm(x_sp, p["ln"].astype(dt), cfg.norm_eps).reshape(b * s_loc, d)
-    y = ag_linear(h, p["wi"].astype(dt), pcfg)  # (tp*B*S_loc, n_up*dff_loc)
-    y = _mlp_act(cfg, y)
-    out = rs_linear(y, p["wo"].astype(dt), pcfg)  # rows already rank-major
-    return x_sp + out.reshape(b, s_loc, d)
+    with obs.scope("mlp"):
+        b, s_loc, d = x_sp.shape
+        dt = x_sp.dtype
+        h = rmsnorm(x_sp, p["ln"].astype(dt), cfg.norm_eps).reshape(b * s_loc, d)
+        y = ag_linear(h, p["wi"].astype(dt), pcfg)  # (tp*B*S_loc, n_up*dff_loc)
+        y = _mlp_act(cfg, y)
+        out = rs_linear(y, p["wo"].astype(dt), pcfg)  # rows already rank-major
+        return x_sp + out.reshape(b, s_loc, d)
 
 
 def mlp_decode(cfg, pcfg, info, p: dict, x: Array) -> Array:
-    b, t, d = x.shape
-    dt = x.dtype
-    h = rmsnorm(x, p["ln"].astype(dt), cfg.norm_eps).reshape(b * t, d)
-    y = _mlp_act(cfg, local_linear(h, p["wi"].astype(dt)))
-    out = psum_tp(local_linear(y, p["wo"].astype(dt)), pcfg)
-    return x + out.reshape(b, t, d)
+    with obs.scope("mlp"):
+        b, t, d = x.shape
+        dt = x.dtype
+        h = rmsnorm(x, p["ln"].astype(dt), cfg.norm_eps).reshape(b * t, d)
+        y = _mlp_act(cfg, local_linear(h, p["wi"].astype(dt)))
+        out = psum_tp(local_linear(y, p["wo"].astype(dt)), pcfg)
+        return x + out.reshape(b, t, d)
 
 
 # ===========================================================================
@@ -639,100 +653,102 @@ def _capacity(t: int, k: int, e: int, factor: float) -> int:
 
 
 def moe_train(cfg, pcfg, info, p: dict, x_sp: Array) -> Array:
-    b, s_loc, d = x_sp.shape
-    tp = pcfg.tp
-    dt = x_sp.dtype
-    ln, router = p["ln"].astype(dt), p["router"].astype(dt)
-    wi, wo = p["wi"].astype(dt), p["wo"].astype(dt)
-    h = rmsnorm(x_sp, ln, cfg.norm_eps).reshape(b * s_loc, d)
-    logits = local_linear(h, router)  # (T_loc, E)
-    k = cfg.experts_per_token
+    with obs.scope("moe"):
+        b, s_loc, d = x_sp.shape
+        tp = pcfg.tp
+        dt = x_sp.dtype
+        ln, router = p["ln"].astype(dt), p["router"].astype(dt)
+        wi, wo = p["wi"].astype(dt), p["wo"].astype(dt)
+        h = rmsnorm(x_sp, ln, cfg.norm_eps).reshape(b * s_loc, d)
+        logits = local_linear(h, router)  # (T_loc, E)
+        k = cfg.experts_per_token
 
-    if info.moe_mode == "ep" and tp > 1:
-        # token chunking bounds the (E, cap, d) dispatch buffers AND is the
-        # natural grain for overlapping a2a(chunk i+1) with experts(chunk i)
-        t_loc = h.shape[0]
-        n_chunks = max(1, min(pcfg.moe_chunks, t_loc))
-        while t_loc % n_chunks != 0:
-            n_chunks -= 1
-        t_c = t_loc // n_chunks
-        cap = _capacity(t_c, k, cfg.num_experts, cfg.capacity_factor)
+        if info.moe_mode == "ep" and tp > 1:
+            # token chunking bounds the (E, cap, d) dispatch buffers AND is the
+            # natural grain for overlapping a2a(chunk i+1) with experts(chunk i)
+            t_loc = h.shape[0]
+            n_chunks = max(1, min(pcfg.moe_chunks, t_loc))
+            while t_loc % n_chunks != 0:
+                n_chunks -= 1
+            t_c = t_loc // n_chunks
+            cap = _capacity(t_c, k, cfg.num_experts, cfg.capacity_factor)
 
-        a2a = pcfg.policy.resolve("a2a_ep")
+            a2a = pcfg.policy.resolve("a2a_ep")
 
-        def ep_chunk(hc, lc):
-            disp, dinfo = mo.topk_dispatch(hc, lc, k, cap)  # (E, cap, D)
-            x_ep = mo.a2a_ep(disp, MODEL_AXIS, mode=a2a.mode,
-                             backend=a2a.backend, wire=a2a.wire)
-            y_ep = _expert_ffn(cfg, x_ep, wi, wo)  # (E_loc, tp*cap, D)
-            back = mo.a2a_ep_inverse(y_ep, MODEL_AXIS, mode=a2a.mode,
-                                     backend=a2a.backend, wire=a2a.wire)
-            return mo.topk_combine(back, dinfo, out_dtype=dt)
+            def ep_chunk(hc, lc):
+                disp, dinfo = mo.topk_dispatch(hc, lc, k, cap)  # (E, cap, D)
+                x_ep = mo.a2a_ep(disp, MODEL_AXIS, mode=a2a.mode,
+                                 backend=a2a.backend, wire=a2a.wire)
+                y_ep = _expert_ffn(cfg, x_ep, wi, wo)  # (E_loc, tp*cap, D)
+                back = mo.a2a_ep_inverse(y_ep, MODEL_AXIS, mode=a2a.mode,
+                                         backend=a2a.backend, wire=a2a.wire)
+                return mo.topk_combine(back, dinfo, out_dtype=dt)
+
+            if pcfg.remat != "none":
+                ep_chunk = jax.checkpoint(ep_chunk)
+            outs = []
+            for ci in range(n_chunks):
+                hc = lax.dynamic_slice(h, (ci * t_c, 0), (t_c, d))
+                lc = lax.dynamic_slice(logits, (ci * t_c, 0), (t_c, logits.shape[1]))
+                outs.append(ep_chunk(hc, lc))
+            out = jnp.concatenate(outs, axis=0) if n_chunks > 1 else outs[0]
+            return x_sp + out.reshape(b, s_loc, d)
+
+        # TP mode: AllGather token chunks around the ring, run the d_ff-sharded
+        # experts per chunk (AG+MoE), then ring-ReduceScatter the partial
+        # outputs (MoE+RS). (EP configs on tp=1 meshes also land here.)
+        cap = _capacity(h.shape[0], k, cfg.num_experts, cfg.capacity_factor)
+
+        def expert_fn(tokens, tok_logits):
+            dsp, dinfo = mo.topk_dispatch(tokens, tok_logits, k, cap)
+            y = _expert_ffn(cfg, dsp, wi, wo)
+            return mo.topk_combine(y, dinfo, out_dtype=tokens.dtype)
 
         if pcfg.remat != "none":
-            ep_chunk = jax.checkpoint(ep_chunk)
-        outs = []
-        for ci in range(n_chunks):
-            hc = lax.dynamic_slice(h, (ci * t_c, 0), (t_c, d))
-            lc = lax.dynamic_slice(logits, (ci * t_c, 0), (t_c, logits.shape[1]))
-            outs.append(ep_chunk(hc, lc))
-        out = jnp.concatenate(outs, axis=0) if n_chunks > 1 else outs[0]
+            # per-ring-chunk checkpoint: the backward live-set is one chunk's
+            # dispatch buffers, not all W chunks' (the ring makes W of them)
+            expert_fn = jax.checkpoint(expert_fn)
+
+        if tp > 1:
+            # ag_moe carries a derived vjp-of-closure backward (the kernel
+            # forward keeps the graph-schedule dual through the ONE shared
+            # custom_vjp), so the TRAIN path follows the policy's backend —
+            # the graph-only pin is gone.
+            ag = pcfg.policy.resolve("ag_moe")
+            full = mo.ag_moe(h, logits, expert_fn, MODEL_AXIS,
+                             mode=ag.mode, backend=ag.backend)
+            rs = pcfg.policy.resolve("reduce_scatter")
+            out = cm.reduce_scatter_chunked(full, MODEL_AXIS, mode=rs.mode,
+                                            backend=rs.backend, wire=rs.wire)
+        else:
+            out = expert_fn(h, logits)
         return x_sp + out.reshape(b, s_loc, d)
-
-    # TP mode: AllGather token chunks around the ring, run the d_ff-sharded
-    # experts per chunk (AG+MoE), then ring-ReduceScatter the partial
-    # outputs (MoE+RS). (EP configs on tp=1 meshes also land here.)
-    cap = _capacity(h.shape[0], k, cfg.num_experts, cfg.capacity_factor)
-
-    def expert_fn(tokens, tok_logits):
-        dsp, dinfo = mo.topk_dispatch(tokens, tok_logits, k, cap)
-        y = _expert_ffn(cfg, dsp, wi, wo)
-        return mo.topk_combine(y, dinfo, out_dtype=tokens.dtype)
-
-    if pcfg.remat != "none":
-        # per-ring-chunk checkpoint: the backward live-set is one chunk's
-        # dispatch buffers, not all W chunks' (the ring makes W of them)
-        expert_fn = jax.checkpoint(expert_fn)
-
-    if tp > 1:
-        # ag_moe carries a derived vjp-of-closure backward (the kernel
-        # forward keeps the graph-schedule dual through the ONE shared
-        # custom_vjp), so the TRAIN path follows the policy's backend —
-        # the graph-only pin is gone.
-        ag = pcfg.policy.resolve("ag_moe")
-        full = mo.ag_moe(h, logits, expert_fn, MODEL_AXIS,
-                         mode=ag.mode, backend=ag.backend)
-        rs = pcfg.policy.resolve("reduce_scatter")
-        out = cm.reduce_scatter_chunked(full, MODEL_AXIS, mode=rs.mode,
-                                        backend=rs.backend, wire=rs.wire)
-    else:
-        out = expert_fn(h, logits)
-    return x_sp + out.reshape(b, s_loc, d)
 
 
 def moe_decode(cfg, pcfg, info, p: dict, x: Array) -> Array:
-    b, t, d = x.shape
-    dt = x.dtype
-    ln, router = p["ln"].astype(dt), p["router"].astype(dt)
-    wi, wo = p["wi"].astype(dt), p["wo"].astype(dt)
-    h = rmsnorm(x, ln, cfg.norm_eps).reshape(b * t, d)
-    logits = local_linear(h, router)
-    k = cfg.experts_per_token
-    cap = _capacity(h.shape[0], k, cfg.num_experts, cfg.capacity_factor)
-    disp, dinfo = mo.topk_dispatch(h, logits, k, cap)
-    if info.moe_mode == "ep" and pcfg.tp > 1:
-        a2a = pcfg.policy.resolve("a2a_ep")
-        x_ep = mo.a2a_ep(disp, MODEL_AXIS, mode=a2a.mode,
-                         backend=a2a.backend, wire=a2a.wire)
-        y_ep = _expert_ffn(cfg, x_ep, wi, wo)
-        back = mo.a2a_ep_inverse(y_ep, MODEL_AXIS, mode=a2a.mode,
-                                 backend=a2a.backend, wire=a2a.wire)
-        out = mo.topk_combine(back, dinfo, out_dtype=dt)
-    else:
-        y = _expert_ffn(cfg, disp, wi, wo)
-        out = mo.topk_combine(y, dinfo, out_dtype=dt)
-        out = psum_tp(out, pcfg) if info.moe_mode == "tp" else out
-    return x + out.reshape(b, t, d)
+    with obs.scope("moe"):
+        b, t, d = x.shape
+        dt = x.dtype
+        ln, router = p["ln"].astype(dt), p["router"].astype(dt)
+        wi, wo = p["wi"].astype(dt), p["wo"].astype(dt)
+        h = rmsnorm(x, ln, cfg.norm_eps).reshape(b * t, d)
+        logits = local_linear(h, router)
+        k = cfg.experts_per_token
+        cap = _capacity(h.shape[0], k, cfg.num_experts, cfg.capacity_factor)
+        disp, dinfo = mo.topk_dispatch(h, logits, k, cap)
+        if info.moe_mode == "ep" and pcfg.tp > 1:
+            a2a = pcfg.policy.resolve("a2a_ep")
+            x_ep = mo.a2a_ep(disp, MODEL_AXIS, mode=a2a.mode,
+                             backend=a2a.backend, wire=a2a.wire)
+            y_ep = _expert_ffn(cfg, x_ep, wi, wo)
+            back = mo.a2a_ep_inverse(y_ep, MODEL_AXIS, mode=a2a.mode,
+                                     backend=a2a.backend, wire=a2a.wire)
+            out = mo.topk_combine(back, dinfo, out_dtype=dt)
+        else:
+            y = _expert_ffn(cfg, disp, wi, wo)
+            out = mo.topk_combine(y, dinfo, out_dtype=dt)
+            out = psum_tp(out, pcfg) if info.moe_mode == "tp" else out
+        return x + out.reshape(b, t, d)
 
 
 # ===========================================================================

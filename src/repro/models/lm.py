@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from .. import obs
 from ..configs.base import ModelConfig, ParallelConfig
 from ..core import flash_decode as dfd
 from ..core import schedules
@@ -455,11 +456,13 @@ class LM:
         assert cfg.family in ("dense", "moe"), cfg.family
         b = token.shape[0]
         cdt = jnp.dtype(pcfg.compute_dtype)
-        embed = fsdp_get(params["top"]["embed"], self.top_specs["embed"], pcfg, cdt)
-        h = embed_lookup(token, embed, info)  # (B, 1, D)
-        if not cfg.use_rope:
-            h = h + sinusoidal_positions(
-                lengths, cfg.d_model)[:, None, :].astype(h.dtype)
+        with obs.scope("embed"):
+            embed = fsdp_get(params["top"]["embed"], self.top_specs["embed"],
+                             pcfg, cdt)
+            h = embed_lookup(token, embed, info)  # (B, 1, D)
+            if not cfg.use_rope:
+                h = h + sinusoidal_positions(
+                    lengths, cfg.d_model)[:, None, :].astype(h.dtype)
 
         def body(carry, xs):
             p_layer, pk, pv = xs
@@ -473,14 +476,18 @@ class LM:
                 hh = blocks.mlp_decode(cfg, pcfg, info, pl["ffn"], hh)
             return hh, (pk, pv)
 
-        h, (pk, pv) = lax.scan(
-            body, h, (params["layers"], pools["attn"]["k"], pools["attn"]["v"]))
-        ln_f = fsdp_get(params["top"]["ln_f"], self.top_specs["ln_f"], pcfg, h.dtype)
-        h = rmsnorm(h, ln_f, cfg.norm_eps).reshape(b, cfg.d_model)
-        un_name = "embed" if cfg.tie_embeddings else "unembed"
-        w_out = fsdp_get(params["top"][un_name], self.top_specs[un_name], pcfg,
-                         h.dtype).T
-        logits = vocab_parallel_logits(h, w_out, info, cfg.vocab_size)
+        with obs.scope("layers"):
+            h, (pk, pv) = lax.scan(
+                body, h,
+                (params["layers"], pools["attn"]["k"], pools["attn"]["v"]))
+        with obs.scope("logits"):
+            ln_f = fsdp_get(params["top"]["ln_f"], self.top_specs["ln_f"],
+                            pcfg, h.dtype)
+            h = rmsnorm(h, ln_f, cfg.norm_eps).reshape(b, cfg.d_model)
+            un_name = "embed" if cfg.tie_embeddings else "unembed"
+            w_out = fsdp_get(params["top"][un_name], self.top_specs[un_name],
+                             pcfg, h.dtype).T
+            logits = vocab_parallel_logits(h, w_out, info, cfg.vocab_size)
         return logits, {"attn": {"k": pk, "v": pv}}
 
     def prefill_chunk_local(
@@ -508,11 +515,14 @@ class LM:
         s_loc = s // tp
         me = lax.axis_index(MODEL_AXIS)
         cdt = jnp.dtype(pcfg.compute_dtype)
-        embed = fsdp_get(params["top"]["embed"], self.top_specs["embed"], pcfg, cdt)
-        h = embed_lookup_sp(tokens, embed, info, tp)
-        if not cfg.use_rope:
-            pos = start + me * s_loc + jnp.arange(s_loc)
-            h = h + sinusoidal_positions(pos, cfg.d_model)[None].astype(h.dtype)
+        with obs.scope("embed"):
+            embed = fsdp_get(params["top"]["embed"], self.top_specs["embed"],
+                             pcfg, cdt)
+            h = embed_lookup_sp(tokens, embed, info, tp)
+            if not cfg.use_rope:
+                pos = start + me * s_loc + jnp.arange(s_loc)
+                h = h + sinusoidal_positions(
+                    pos, cfg.d_model)[None].astype(h.dtype)
 
         def body(carry, xs):
             p_layer, pk, pv = xs
@@ -525,24 +535,28 @@ class LM:
                 hh = blocks.mlp_train(cfg, pcfg, info, pl["ffn"], hh)
             return hh, (pk, pv)
 
-        h, (pk, pv) = lax.scan(
-            self._remat(body), h,
-            (params["layers"], pools["attn"]["k"], pools["attn"]["v"]))
-        ln_f = fsdp_get(params["top"]["ln_f"], self.top_specs["ln_f"], pcfg, h.dtype)
-        # logits of the LAST VALID chunk token (the next-token logits when
-        # this is the prompt's final chunk); it lives on model rank
-        # idx // s_loc — replicate that row over TP before the
-        # vocab-parallel projection (see prefill_logits_local)
-        idx = jnp.maximum(n_valid - 1, 0)
-        local_idx = jnp.clip(idx - me * s_loc, 0, s_loc - 1)
-        h_sel = lax.dynamic_slice(h, (0, local_idx, 0), (b, 1, cfg.d_model))[:, 0]
-        keep = (me == idx // s_loc).astype(h.dtype)
-        h_last = lax.psum(h_sel * keep, MODEL_AXIS)
-        h_last = rmsnorm(h_last, ln_f, cfg.norm_eps)
-        un_name = "embed" if cfg.tie_embeddings else "unembed"
-        w_out = fsdp_get(params["top"][un_name], self.top_specs[un_name], pcfg,
-                         h.dtype).T
-        logits = vocab_parallel_logits(h_last, w_out, info, cfg.vocab_size)
+        with obs.scope("layers"):
+            h, (pk, pv) = lax.scan(
+                self._remat(body), h,
+                (params["layers"], pools["attn"]["k"], pools["attn"]["v"]))
+        with obs.scope("logits"):
+            ln_f = fsdp_get(params["top"]["ln_f"], self.top_specs["ln_f"],
+                            pcfg, h.dtype)
+            # logits of the LAST VALID chunk token (the next-token logits
+            # when this is the prompt's final chunk); it lives on model rank
+            # idx // s_loc — replicate that row over TP before the
+            # vocab-parallel projection (see prefill_logits_local)
+            idx = jnp.maximum(n_valid - 1, 0)
+            local_idx = jnp.clip(idx - me * s_loc, 0, s_loc - 1)
+            h_sel = lax.dynamic_slice(
+                h, (0, local_idx, 0), (b, 1, cfg.d_model))[:, 0]
+            keep = (me == idx // s_loc).astype(h.dtype)
+            h_last = lax.psum(h_sel * keep, MODEL_AXIS)
+            h_last = rmsnorm(h_last, ln_f, cfg.norm_eps)
+            un_name = "embed" if cfg.tie_embeddings else "unembed"
+            w_out = fsdp_get(params["top"][un_name], self.top_specs[un_name],
+                             pcfg, h.dtype).T
+            logits = vocab_parallel_logits(h_last, w_out, info, cfg.vocab_size)
         return logits, {"attn": {"k": pk, "v": pv}}
 
     def prefill_chunk_cp_local(

@@ -1,8 +1,62 @@
-"""repro.obs — per-PE overlap timelines for the shmem engine.
+"""repro.obs — marks for the profiler, and per-PE overlap timelines for
+the shmem engine.
+
+Two marks serve every path:
+
+* :func:`scope` (``jax.named_scope``) names the device operations traced
+  inside it. It acts at trace time: the name lands in each instruction's
+  ``op_name`` metadata (``jit(f)/layers/while/body/closed_call/attn/
+  paged_gather/gather``) and costs nothing at run time.
+* :func:`span` (``jax.profiler.TraceAnnotation``) is a host span on the
+  profiler's clock; its integer counters become the event's stats in the
+  ``.xplane.pb``. With no profiler running it costs about a microsecond.
+
+The paged serving path carries these (``serve/engine.py``
+``PagedEngine.step``; ``models/lm.py``, ``models/blocks.py``). Host spans
+nest as listed; scopes sit inside the two programs ``jit_paged_decode_step``
+and ``jit_prefill_chunk_step`` (``launch/steps.py``):
+
+==========================  =====  ==========================================
+name                        kind   meaning (counters)
+==========================  =====  ==========================================
+``serve.step``              span   one working engine step (``queue``: queue
+                                   depth after admission; ``slots``: slots
+                                   holding a request; ``pages``: pages held)
+``serve.schedule``          span   admission and planning
+``serve.prefill``           span   the step's chunked-prefill call
+                                   (``tokens``: valid prompt tokens of the
+                                   chunk; ``streams``: streams with a chunk)
+``serve.decode``            span   the step's decode call (``slots``:
+                                   decoding slots; ``context``: the sum of
+                                   their cached lengths)
+``serve.<phase>.launch``    span   build and upload the host arrays, dispatch
+``serve.<phase>.fetch``     span   ``np.asarray(logits)``: the wait for the
+                                   program and the copy back
+``serve.<phase>.sample``    span   sampling and emitting the tokens
+``embed``                   scope  the embedding lookup
+``layers``                  scope  the ``lax.scan`` over layers; its own
+                                   operations (slicing each layer's weights
+                                   and pools out of the stacked leaves, the
+                                   write-back) lie outside the body's
+                                   ``closed_call``
+``attn``                    scope  one attention layer, with children
+                                   ``qkv`` (norm, projections, rope),
+                                   ``kv_write`` (the pool writes),
+                                   ``paged_gather`` (pages to per-slot K/V),
+                                   ``flash_decode`` (decode) or
+                                   ``chunk_attend`` (prefill), ``out``
+                                   (output projection and its reduction)
+``mlp`` / ``moe``           scope  the feed-forward block
+``logits``                  scope  final norm and unembedding
+==========================  =====  ==========================================
+
+The spans and scopes need no switch: ``jax.profiler.trace`` records them
+and they cost nearly nothing without it. The ``enable()`` switch below
+belongs to the shmem timelines alone.
 
 The paper's claim is that compiler-generated overlapping kernels hide
-communication latency. This package makes the overlap *visible*: when
-tracing is enabled, every host-side op of the emulated DMA backend
+communication latency. The rest of this package makes the overlap
+*visible*: when tracing is enabled, every host-side op of the emulated DMA backend
 (:mod:`repro.shmem.emulated`) appends a timestamped per-PE
 :class:`TraceEvent` into its world's ring buffer — puts, signals,
 credit/arrival waits, barriers, reads — and the tile executor brackets
@@ -30,9 +84,9 @@ Semantics
   will keep reusing it). With tracing disabled the traced program is the
   seed program — outputs are bit-identical.
 * On the real-TPU pltpu backend there are no host callbacks to
-  timestamp; the SAME span labels are mapped onto ``jax.named_scope`` +
-  ``jax.profiler.TraceAnnotation`` (see :func:`phase`), so a real
-  profiler capture (``jax.profiler.trace``) carries identical
+  timestamp; the SAME span labels are mapped onto a :func:`scope` and a
+  :func:`span` (see :func:`phase`), so a real profiler capture
+  (``jax.profiler.trace``) carries identical
   ``obs.tile_compute`` / ``obs.pack`` / ``obs.decode`` labels.
 * Trace buffers live per shmem world (per traced-kernel instance) and
   are bounded rings: ``enable(capacity=...)`` sets the per-world event
@@ -54,6 +108,8 @@ import collections
 import contextlib
 import threading
 from typing import List, NamedTuple, Optional
+
+import jax
 
 
 class TraceEvent(NamedTuple):
@@ -156,27 +212,28 @@ def clear() -> None:
     events(clear=True)
 
 
+def scope(name: str):
+    """A device scope: ``jax.named_scope(name)``. Operations traced inside
+    it carry ``name`` in their ``op_name`` metadata; nothing runs."""
+    return jax.named_scope(name)
+
+
+def span(name: str, **counts: int):
+    """A host span on the profiler's clock:
+    ``jax.profiler.TraceAnnotation(name, **counts)``. Each counter becomes
+    a stat of the trace event; ``set_metadata(**counts)`` on the span adds
+    counters known only after it opened."""
+    return jax.profiler.TraceAnnotation(name, **counts)
+
+
 @contextlib.contextmanager
 def phase(kind: str, name: str = ""):
-    """The backend-independent span label: ``obs.<kind>[.<name>]``.
-
-    Enters ``jax.named_scope`` (the label lands in XLA op metadata, so
-    real-TPU profiles of the pltpu protocols carry the same
-    ``obs.tile_compute`` / ``obs.pack`` / ``obs.decode`` names the
-    emulated timeline records) and, when available,
-    ``jax.profiler.TraceAnnotation`` (host-side perfetto annotation for
-    profiled runs). Zero runtime cost inside jit — named scopes are
-    trace-time metadata.
-    """
-    import jax
-
+    """The backend-independent span label: ``obs.<kind>[.<name>]``, both
+    as a :func:`scope` (real-TPU profiles of the pltpu protocols carry the
+    same ``obs.tile_compute`` / ``obs.pack`` / ``obs.decode`` names the
+    emulated timeline records) and as a host :func:`span`."""
     label = f"obs.{kind}" + (f".{name}" if name else "")
-    with contextlib.ExitStack() as stack:
-        stack.enter_context(jax.named_scope(label))
-        try:
-            stack.enter_context(jax.profiler.TraceAnnotation(label))
-        except Exception:  # profiler backend unavailable: label via scope only
-            pass
+    with scope(label), span(label):
         yield
 
 
@@ -194,6 +251,8 @@ __all__ = [
     "events",
     "clear",
     "phase",
+    "scope",
+    "span",
     "metrics",
     "trace",
 ]

@@ -24,6 +24,13 @@ counters as they run — TTFT (arrival -> first generated token), TPOT
 (mean seconds per output token after the first), queue depth and slot
 occupancy sampled per step, prefill-vs-decode step split — reduced into
 a :class:`Metrics` snapshot via ``metrics()``.
+
+Tracing: ``PagedEngine.step`` marks its phases with host spans
+(``serve.step``, ``serve.schedule``, ``serve.prefill``, ``serve.decode``
+and their ``.launch`` / ``.fetch`` / ``.sample``), with counters; see
+:mod:`repro.obs` for the table. A profiler capture
+(``jax.profiler.trace``) records them; without one they cost about a
+microsecond each.
 """
 from __future__ import annotations
 
@@ -34,6 +41,7 @@ from typing import Callable, List, Optional
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from .kvcache import PagedKVCache
 from .scheduler import Scheduler, ServeConfig
 
@@ -113,8 +121,16 @@ class _EngineBase:
         self._tokens_completed = 0
         self._ttfts: List[float] = []
         self._tpots: List[float] = []
-        self._queue_samples: List[int] = []
-        self._occ_samples: List[float] = []
+        # per-step samples of queue depth and slot occupancy, kept as
+        # running sums (and the deepest queue) for the server's lifetime
+        self._queue_sum = 0
+        self._queue_max = 0
+        self._occ_sum = 0.0
+
+    def _sample_load(self, queue: int, occupancy: float) -> None:
+        self._queue_sum += queue
+        self._queue_max = max(self._queue_max, queue)
+        self._occ_sum += occupancy
 
     def _finish(self, req: Request, now: float) -> None:
         req.done = True
@@ -145,9 +161,9 @@ class _EngineBase:
             ttft_max_s=max(self._ttfts, default=0.0),
             tpot_mean_s=(sum(self._tpots) / len(self._tpots)
                          if self._tpots else 0.0),
-            queue_depth_mean=sum(self._queue_samples) / n_steps,
-            queue_depth_max=max(self._queue_samples, default=0),
-            slot_occupancy_mean=sum(self._occ_samples) / n_steps,
+            queue_depth_mean=self._queue_sum / n_steps,
+            queue_depth_max=self._queue_max,
+            slot_occupancy_mean=self._occ_sum / n_steps,
             steps_prefill=self._steps_prefill,
             steps_decode=self._steps_decode,
             requests_truncated=self._truncated,
@@ -259,9 +275,8 @@ class Engine(_EngineBase):
         self._admit()
         if all(r is None for r in self.requests) and not self.pending:
             return False
-        self._queue_samples.append(len(self.pending))
-        self._occ_samples.append(
-            sum(r is not None for r in self.requests) / self.batch)
+        self._sample_load(len(self.pending),
+                          sum(r is not None for r in self.requests) / self.batch)
         toks = self._next_tokens(self._last)
         logits, self.caches = self.step_fn(
             self.params, self.caches, jnp.asarray(self.slot_lens),
@@ -418,63 +433,77 @@ class PagedEngine(_EngineBase):
         n_streams = self.dp_shards
         p = self.kv.pages_per_slot
         c = self.scfg.chunk
-        table = np.zeros((n_streams, p), np.int32)
-        starts = np.zeros((n_streams,), np.int32)
-        nvalid = np.zeros((n_streams,), np.int32)
-        toks = np.zeros((n_streams, c), np.int32)
-        for slot_id, start, n in items:
-            sh = self.kv.shard(slot_id)
-            table[sh] = self.kv.table[slot_id]
-            starts[sh] = start
-            nvalid[sh] = n
-            toks[sh, :n] = self.sched.slots[slot_id].req.prompt[start:start + n]
-        logits, self.pools = self.prefill_fn(
-            self.params, self.pools, jnp.asarray(table), jnp.asarray(starts),
-            jnp.asarray(nvalid), jnp.asarray(toks))
+        with obs.span("serve.prefill.launch"):
+            table = np.zeros((n_streams, p), np.int32)
+            starts = np.zeros((n_streams,), np.int32)
+            nvalid = np.zeros((n_streams,), np.int32)
+            toks = np.zeros((n_streams, c), np.int32)
+            for slot_id, start, n in items:
+                sh = self.kv.shard(slot_id)
+                table[sh] = self.kv.table[slot_id]
+                starts[sh] = start
+                nvalid[sh] = n
+                toks[sh, :n] = self.sched.slots[slot_id].req.prompt[start:start + n]
+            logits, self.pools = self.prefill_fn(
+                self.params, self.pools, jnp.asarray(table),
+                jnp.asarray(starts), jnp.asarray(nvalid), jnp.asarray(toks))
         self._steps_prefill += 1
-        logits = np.asarray(logits)
+        with obs.span("serve.prefill.fetch"):
+            logits = np.asarray(logits)
         now = time.perf_counter()
-        for slot_id, start, n in items:
-            s = self.sched.slots[slot_id]
-            if self.sched.note_chunk(slot_id, n):
-                tok = _sample_row(self.rng, logits[self.kv.shard(slot_id)],
-                                  s.req.temperature)
-                self._emit(slot_id, tok, now)
+        with obs.span("serve.prefill.sample"):
+            for slot_id, start, n in items:
+                s = self.sched.slots[slot_id]
+                if self.sched.note_chunk(slot_id, n):
+                    tok = _sample_row(self.rng, logits[self.kv.shard(slot_id)],
+                                      s.req.temperature)
+                    self._emit(slot_id, tok, now)
 
     def _decode_step(self, slot_ids) -> None:
         b = self.scfg.batch
-        toks = np.zeros((b, 1), np.int32)
-        active = np.zeros((b,), bool)
-        for i in slot_ids:
-            toks[i, 0] = self.sched.slots[i].last_token
-            active[i] = True
-        logits, self.pools = self.decode_fn(
-            self.params, self.pools, jnp.asarray(self.kv.table),
-            jnp.asarray(self.kv.lens), jnp.asarray(active),
-            jnp.asarray(toks))
+        with obs.span("serve.decode.launch"):
+            toks = np.zeros((b, 1), np.int32)
+            active = np.zeros((b,), bool)
+            for i in slot_ids:
+                toks[i, 0] = self.sched.slots[i].last_token
+                active[i] = True
+            logits, self.pools = self.decode_fn(
+                self.params, self.pools, jnp.asarray(self.kv.table),
+                jnp.asarray(self.kv.lens), jnp.asarray(active),
+                jnp.asarray(toks))
         self._steps_decode += 1
-        logits = np.asarray(logits)
+        with obs.span("serve.decode.fetch"):
+            logits = np.asarray(logits)
         now = time.perf_counter()
-        for i in slot_ids:
-            s = self.sched.slots[i]
-            self.sched.note_decode(i)
-            tok = _sample_row(self.rng, logits[i], s.req.temperature)
-            self._emit(i, tok, now)
+        with obs.span("serve.decode.sample"):
+            for i in slot_ids:
+                s = self.sched.slots[i]
+                self.sched.note_decode(i)
+                tok = _sample_row(self.rng, logits[i], s.req.temperature)
+                self._emit(i, tok, now)
 
     def step(self) -> bool:
         """One scheduler iteration: admit, plan one mixed prefill+decode
         batch under the token budget, execute. False when idle."""
-        self.sched.admit()
-        if self.sched.idle():
+        if self.sched.idle():  # admission cannot make an idle engine busy
             return False
-        self._queue_samples.append(self.sched.queue_depth())
-        self._occ_samples.append(self.sched.occupancy())
-        plan = self.sched.plan()
-        if plan.prefill:
-            self._prefill_step(plan.prefill)
-        if plan.decode:
-            self._decode_step(plan.decode)
-        self._steps += 1
+        with obs.span("serve.step") as step_span:
+            with obs.span("serve.schedule"):
+                self.sched.admit()
+                plan = self.sched.plan()
+            queue, slots = self.sched.queue_depth(), self.sched.busy_slots()
+            step_span.set_metadata(queue=queue, slots=slots,
+                                   pages=self.kv.pages_held())
+            self._sample_load(queue, slots / self.scfg.batch)
+            if plan.prefill:
+                with obs.span("serve.prefill", streams=len(plan.prefill),
+                              tokens=sum(n for _, _, n in plan.prefill)):
+                    self._prefill_step(plan.prefill)
+            if plan.decode:
+                with obs.span("serve.decode", slots=len(plan.decode),
+                              context=int(self.kv.lens[plan.decode].sum())):
+                    self._decode_step(plan.decode)
+            self._steps += 1
         return True
 
     def run(self, max_steps: int = 10_000):

@@ -104,8 +104,11 @@ class PagedKVCache:
         self.table[slot] = 0
         self.lens[slot] = 0
 
+    def pages_held(self) -> int:
+        """Non-scratch pages currently allocated, over all shards."""
+        total = self.dp_shards * (self.num_pages - 1)
+        return total - sum(len(f) for f in self._free)
+
     def occupancy(self) -> float:
         """Fraction of non-scratch pages currently allocated."""
-        total = self.dp_shards * (self.num_pages - 1)
-        free = sum(len(f) for f in self._free)
-        return (total - free) / max(1, total)
+        return self.pages_held() / max(1, self.dp_shards * (self.num_pages - 1))

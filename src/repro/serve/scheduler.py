@@ -75,8 +75,12 @@ class Scheduler:
     def queue_depth(self) -> int:
         return len(self.queue)
 
+    def busy_slots(self) -> int:
+        """Slots holding a request."""
+        return sum(s.phase != "idle" for s in self.slots)
+
     def occupancy(self) -> float:
-        return sum(s.phase != "idle" for s in self.slots) / self.scfg.batch
+        return self.busy_slots() / self.scfg.batch
 
     def idle(self) -> bool:
         return not self.queue and all(s.phase == "idle" for s in self.slots)
