@@ -30,7 +30,9 @@ def _platform() -> str:
     return jax.default_backend()
 
 
-def _use_pallas(force: Force) -> bool:
+def use_pallas(force: Force = None) -> bool:
+    """Whether the kernels run as Pallas here (compiled on a TPU,
+    interpreted elsewhere) rather than as ref.py's jnp math."""
     if force is not None:
         return force == "pallas"
     return _platform() == "tpu"
@@ -52,7 +54,7 @@ def _pad_to(x: jax.Array, mult: int, axis: int) -> jax.Array:
 
 def matmul(a, b, *, out_dtype=jnp.float32, bm=256, bk=512, bn=256,
            rank=0, world=1, force: Force = None):
-    if not _use_pallas(force):
+    if not use_pallas(force):
         return _ref.matmul(a, b, out_dtype)
     m, k = a.shape
     _, n = b.shape
@@ -66,7 +68,7 @@ def matmul(a, b, *, out_dtype=jnp.float32, bm=256, bk=512, bn=256,
 
 def grouped_matmul(x, w, *, out_dtype=jnp.float32, bm=128, bk=512, bn=256,
                    force: Force = None):
-    if not _use_pallas(force):
+    if not use_pallas(force):
         return _ref.grouped_matmul(x, w, out_dtype)
     e, cap, k = x.shape
     _, _, n = w.shape
@@ -80,7 +82,7 @@ def grouped_matmul(x, w, *, out_dtype=jnp.float32, bm=128, bk=512, bn=256,
 
 def flash_attention(q, k, v, *, causal=True, scale=None, bq=256, bkv=256,
                     force: Force = None):
-    if not _use_pallas(force):
+    if not use_pallas(force):
         if k.shape[2] > 1024:
             # long sequences: chunked online softmax (O(Lq*chunk) memory)
             return _ref.flash_attention_chunked(q, k, v, causal=causal, scale=scale)
@@ -90,14 +92,25 @@ def flash_attention(q, k, v, *, causal=True, scale=None, bq=256, bkv=256,
 
 
 def flash_decode(q, k, v, length, *, scale=None, bkv=512, force: Force = None):
-    if not _use_pallas(force):
+    if not use_pallas(force):
         return _ref.flash_decode(q, k, v, scale=scale, length=length)
     return _fd.flash_decode(q, k, v, length, scale=scale, bkv=bkv,
                             interpret=_interpret())
 
 
+def paged_flash_decode(q, pool_k, pool_v, table, length, *, scale=None,
+                       bkv=512, force: Force = None):
+    """Flash decode over a page pool through per-slot block tables."""
+    if not use_pallas(force):
+        return _ref.paged_flash_decode(q, pool_k, pool_v, table, length,
+                                       scale=scale)
+    return _fd.paged_flash_decode(q, pool_k, pool_v, table, length,
+                                  scale=scale, bkv=bkv,
+                                  interpret=_interpret())
+
+
 def ssd_scan(x, dt, a, b_mat, c_mat, *, chunk=128, force: Force = None):
-    if not _use_pallas(force):
+    if not use_pallas(force):
         # chunked closed form: O(L/chunk)-deep scan (the per-timestep
         # reference would save a state residual per step in backward)
         return _ref.ssd_scan_chunked(x, dt, a, b_mat, c_mat, chunk=chunk)

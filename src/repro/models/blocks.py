@@ -29,6 +29,7 @@ from ..configs.base import ModelConfig, ParallelConfig
 from ..core import collective_matmul as cm
 from ..core import moe_overlap as mo
 from ..kernels import ops
+from ..kernels import ref as kref
 from .common import (
     DATA_AXIS,
     MODEL_AXIS,
@@ -318,18 +319,41 @@ def attention_decode(
 # ===========================================================================
 
 
-def _gather_pages(pool: Array, table: Array) -> Array:
-    """Materialize per-slot KV from the page pool.
-
-    pool (num_pages, H, page_size, hd), table (B, P) int32 ->
+def _gather_pages(pool: Array, table: Array, hd: int) -> Array:
+    """Materialize per-slot KV from the page pool (``ref.gather_pages``):
     (B, H, P*page_size, hd). Unallocated table entries point at scratch
-    page 0; callers mask those positions out by length.
-    """
-    _, h, ps, hd = pool.shape
-    b, pcount = table.shape
+    page 0; callers mask those positions out by length."""
     with obs.scope("paged_gather"):
-        g = pool[table]  # (B, P, H, ps, hd)
-        return g.transpose(0, 2, 1, 3, 4).reshape(b, h, pcount * ps, hd)
+        return kref.gather_pages(pool, table, hd)
+
+
+def _write_pages(pool: Array, table: Array, start: Array, n_valid: Array,
+                 x: Array) -> Array:
+    """Write x (S, C, H, hd), C consecutive tokens of each of S streams:
+    token t of stream s lands at position ``start[s] + t`` of the pages its
+    block-table row ``table[s]`` lists, for ``t < n_valid[s]``.
+
+    A pool row holds ``parts`` tokens (``page_rows``), so each row the
+    tokens touch is read, the tokens merged in and the row written back
+    whole: XLA runs a scatter of whole rows as one scatter, but expands
+    a scatter into part of a lane row into a loop over its updates.
+    Rows with nothing to write go to scratch page 0."""
+    s, c, h, hd = x.shape
+    rows, width = pool.shape[2:]
+    parts = width // hd
+    n = (c + parts - 2) // parts + 1  # rows that c tokens can touch
+    row = start[:, None] // parts + jnp.arange(n)  # (S, n) in the sequence
+    tok = row[..., None] * parts + jnp.arange(parts) - start[:, None, None]
+    new = (tok >= 0) & (tok < n_valid[:, None, None])  # (S, n, parts)
+    slot_page = jnp.minimum(row // rows, table.shape[1] - 1)
+    page = jnp.where(new.any(-1), jnp.take_along_axis(table, slot_page, 1), 0)
+    at = row % rows
+    old = pool[page, :, at, :].reshape(s, n, h, parts, hd)
+    put = jnp.take_along_axis(
+        x, jnp.clip(tok, 0, c - 1).reshape(s, n * parts, 1, 1), 1)
+    put = put.reshape(s, n, parts, h, hd).transpose(0, 1, 3, 2, 4)
+    merged = jnp.where(new[:, :, None, :, None], put.astype(pool.dtype), old)
+    return pool.at[page, :, at, :].set(merged.reshape(s, n, h, width))
 
 
 def attention_decode_paged(
@@ -338,7 +362,7 @@ def attention_decode_paged(
     info: TPInfo,
     p: dict,
     x: Array,        # (B, 1, D) replicated over tp
-    pool_k: Array,   # (num_pages, Hkv_loc, page_size, hd)
+    pool_k: Array,   # (num_pages, Hkv_loc, rows, width): page_rows
     pool_v: Array,
     table: Array,    # (B, P) int32 page ids
     lengths: Array,  # (B,) tokens already cached per slot
@@ -346,10 +370,10 @@ def attention_decode_paged(
 ) -> Tuple[Array, Array, Array]:
     """Decode-step attention against the paged KV pool: write this
     token's K/V at each live slot's next position (routed through its
-    block table), then flash-decode over the slot's gathered pages."""
+    block table), then flash-decode over each slot's pages: read in
+    place by the paged kernel, or gathered whole on the reference path."""
     b, _, d = x.shape
     hd = cfg.head_dim
-    ps = pool_k.shape[2]
     pp = _get_attn(p, x.dtype)
     with obs.scope("attn"):
         with obs.scope("qkv"):
@@ -362,16 +386,19 @@ def attention_decode_paged(
                 q = rope(q[:, None], pos[:, None], cfg.rope_theta)[:, 0]
                 k_new = rope(k_new[:, None], pos[:, None], cfg.rope_theta)[:, 0]
         with obs.scope("kv_write"):
-            rows = jnp.arange(b)
-            page = jnp.where(active, table[rows, pos // ps], 0)
-            off = pos % ps
-            pool_k = pool_k.at[page, :, off, :].set(k_new.astype(pool_k.dtype))
-            pool_v = pool_v.at[page, :, off, :].set(v_new.astype(pool_v.dtype))
-        k_all = _gather_pages(pool_k, table)
-        v_all = _gather_pages(pool_v, table)
-        with obs.scope("flash_decode"):
-            eff = jnp.where(active, pos + 1, 1)
-            o, _ = ops.flash_decode(q, k_all, v_all, eff)
+            one = active.astype(jnp.int32)  # idle lanes write nothing
+            pool_k = _write_pages(pool_k, table, pos, one, k_new[:, None])
+            pool_v = _write_pages(pool_v, table, pos, one, v_new[:, None])
+        eff = jnp.where(active, pos + 1, 1)
+        if ops.use_pallas():
+            # the kernel reads each slot's live pages in place
+            with obs.scope("flash_decode"):
+                o, _ = ops.paged_flash_decode(q, pool_k, pool_v, table, eff)
+        else:
+            k_all = _gather_pages(pool_k, table, hd)
+            v_all = _gather_pages(pool_v, table, hd)
+            with obs.scope("flash_decode"):
+                o, _ = ops.flash_decode(q, k_all, v_all, eff)
         with obs.scope("out"):
             o = o.astype(x.dtype).reshape(b, info.hq_loc * hd)
             out = psum_tp(local_linear(o, pp.wo), pcfg)
@@ -404,7 +431,7 @@ def attention_prefill_chunk(
     info: TPInfo,
     p: dict,
     x_sp: Array,       # (1, C_loc, D) — one request's chunk, SP over tp
-    pool_k: Array,     # (num_pages, Hkv_loc, page_size, hd)
+    pool_k: Array,     # (num_pages, Hkv_loc, rows, width): page_rows
     pool_v: Array,
     table_row: Array,  # (P,) int32 — the request's block table
     start: Array,      # scalar int32: absolute position of the chunk's 1st token
@@ -419,7 +446,6 @@ def attention_prefill_chunk(
     tp = pcfg.tp
     c = s_loc * tp
     hd = cfg.head_dim
-    ps = pool_k.shape[2]
     pp = _get_attn(p, x_sp.dtype)
 
     with obs.scope("attn"):
@@ -440,14 +466,12 @@ def attention_prefill_chunk(
                 k = rope(k, pos, cfg.rope_theta)
 
         with obs.scope("kv_write"):
-            valid = jnp.arange(c) < n_valid
-            pages = jnp.where(valid, table_row[pos // ps], 0)
-            offs = pos % ps
-            pool_k = pool_k.at[pages, :, offs, :].set(k[0].astype(pool_k.dtype))
-            pool_v = pool_v.at[pages, :, offs, :].set(v[0].astype(pool_v.dtype))
+            rows, first, n = table_row[None], start[None], n_valid[None]
+            pool_k = _write_pages(pool_k, rows, first, n, k)
+            pool_v = _write_pages(pool_v, rows, first, n, v)
 
-        k_all = _gather_pages(pool_k, table_row[None, :])
-        v_all = _gather_pages(pool_v, table_row[None, :])
+        k_all = _gather_pages(pool_k, table_row[None, :], hd)
+        v_all = _gather_pages(pool_v, table_row[None, :], hd)
         with obs.scope("chunk_attend"):
             # all-masked rows would NaN; an idle shard (n_valid == 0) attends
             # one scratch position instead, and the caller discards its output
@@ -488,7 +512,7 @@ def attention_prefill_chunk_cp(
     info: TPInfo,
     p: dict,
     x_sp: Array,       # (1, C/(cp*tp), D) — this rank's placement rows, SP over tp
-    pool_k: Array,     # (num_pages, Hkv_loc, page_size, hd)
+    pool_k: Array,     # (num_pages, Hkv_loc, rows, width): page_rows
     pool_v: Array,
     table_row: Array,  # (P,) int32 — the request's block table
     start: Array,      # scalar int32: absolute position of the chunk's 1st token
@@ -517,7 +541,6 @@ def attention_prefill_chunk_cp(
     tp = pcfg.tp
     c_own = s_loc * tp  # this cp rank's chunk rows
     hd = cfg.head_dim
-    ps = pool_k.shape[2]
     pp = _get_attn(p, x_sp.dtype)
 
     h = rmsnorm(x_sp, pp.ln, cfg.norm_eps).reshape(b * s_loc, d)
@@ -539,16 +562,12 @@ def attention_prefill_chunk_cp(
     # performs the identical pool write — replicas stay bitwise equal
     k_ord = lax.all_gather(k[0], DATA_AXIS, axis=0, tiled=True)[inv_perm]
     v_ord = lax.all_gather(v[0], DATA_AXIS, axis=0, tiled=True)[inv_perm]
-    c = k_ord.shape[0]
-    pos = start + jnp.arange(c)
-    valid = jnp.arange(c) < n_valid
-    pages = jnp.where(valid, table_row[pos // ps], 0)
-    offs = pos % ps
-    pool_k = pool_k.at[pages, :, offs, :].set(k_ord.astype(pool_k.dtype))
-    pool_v = pool_v.at[pages, :, offs, :].set(v_ord.astype(pool_v.dtype))
+    rows, first, n = table_row[None], start[None], n_valid[None]
+    pool_k = _write_pages(pool_k, rows, first, n, k_ord[None])
+    pool_v = _write_pages(pool_v, rows, first, n, v_ord[None])
 
-    k_all = _gather_pages(pool_k, table_row[None, :])
-    v_all = _gather_pages(pool_v, table_row[None, :])
+    k_all = _gather_pages(pool_k, table_row[None, :], hd)
+    v_all = _gather_pages(pool_v, table_row[None, :], hd)
     limit = start + jnp.maximum(n_valid, 1)
     if cp_attend == "dense":
         o = _chunk_attend(q, k_all, v_all, pos_own, limit)

@@ -25,6 +25,7 @@ from .. import obs
 from ..configs.base import ModelConfig, ParallelConfig
 from ..core import flash_decode as dfd
 from ..core import schedules
+from ..kernels.flash_decode import page_rows
 from . import blocks
 from .common import (
     DATA_AXIS,
@@ -431,12 +432,14 @@ class LM:
     def paged_cache_shapes(self, num_pages: int, page_size: int,
                            dtype=jnp.bfloat16):
         """ShapeDtypeStructs for the paged decode pools (dense/moe,
-        heads-sharded KV), stacked over n_super like cache_shapes."""
+        heads-sharded KV), stacked over n_super like cache_shapes; each
+        head's page stored as ``page_rows`` lays it out."""
         cfg, info = self.cfg, self.info
         assert cfg.family in ("dense", "moe"), cfg.family
         assert not self._kv_seq_sharded(), "paged KV is heads-sharded"
         n = self.plan.n_super
-        shape = (n, num_pages, info.hkv_loc, page_size, cfg.head_dim)
+        shape = (n, num_pages, info.hkv_loc,
+                 *page_rows(page_size, cfg.head_dim))
         return {"attn": {"k": jax.ShapeDtypeStruct(shape, dtype),
                          "v": jax.ShapeDtypeStruct(shape, dtype)}}
 

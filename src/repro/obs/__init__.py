@@ -28,7 +28,9 @@ name                        kind   meaning (counters)
                                    chunk; ``streams``: streams with a chunk)
 ``serve.decode``            span   the step's decode call (``slots``:
                                    decoding slots; ``context``: the sum of
-                                   their cached lengths)
+                                   their cached lengths; ``pages``: the
+                                   pages the decode kernel reads, the sum
+                                   of ``ceil((length + 1) / page_size)``)
 ``serve.<phase>.launch``    span   build and upload the host arrays, dispatch
 ``serve.<phase>.fetch``     span   ``np.asarray(logits)``: the wait for the
                                    program and the copy back
@@ -42,7 +44,8 @@ name                        kind   meaning (counters)
 ``attn``                    scope  one attention layer, with children
                                    ``qkv`` (norm, projections, rope),
                                    ``kv_write`` (the pool writes),
-                                   ``paged_gather`` (pages to per-slot K/V),
+                                   ``paged_gather`` (pages to per-slot K/V;
+                                   prefill, and decode off the chip),
                                    ``flash_decode`` (decode) or
                                    ``chunk_attend`` (prefill), ``out``
                                    (output projection and its reduction)

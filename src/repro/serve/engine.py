@@ -500,8 +500,13 @@ class PagedEngine(_EngineBase):
                               tokens=sum(n for _, _, n in plan.prefill)):
                     self._prefill_step(plan.prefill)
             if plan.decode:
+                lens = self.kv.lens[plan.decode]
+                # pages: what the decode kernel reads, each slot's cached
+                # tokens and the new one
                 with obs.span("serve.decode", slots=len(plan.decode),
-                              context=int(self.kv.lens[plan.decode].sum())):
+                              context=int(lens.sum()),
+                              pages=int((lens // self.kv.page_size + 1)
+                                        .sum())):
                     self._decode_step(plan.decode)
             self._steps += 1
         return True

@@ -1,8 +1,10 @@
 """Paged KV cache: block tables + free-list page allocation (host side).
 
 Device side, each attention layer's KV lives in a PAGE POOL
-``(num_pages, Hkv_loc, page_size, hd)`` instead of a dense per-slot
-``(B, Hkv_loc, S_max, hd)`` buffer. A request's tokens map onto pool
+``(num_pages, Hkv_loc, rows, width)`` instead of a dense per-slot
+``(B, Hkv_loc, S_max, hd)`` buffer: each head's page holds its
+``page_size`` tokens in order, ``width / hd`` tokens to a lane row
+(``kernels.flash_decode.page_rows``). A request's tokens map onto pool
 pages through its BLOCK-TABLE row (``pages_per_slot`` page ids), so
 requests of wildly different lengths pack densely and a freed slot's
 pages simply return to the free list — the successor request gets a

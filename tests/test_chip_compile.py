@@ -7,6 +7,7 @@ one process at a time may load the TPU library, and the test workers
 must all collect the same tests. Keep every such compile in this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -65,6 +66,22 @@ def test_flash_decode_compiles_at_serving_shapes(one_chip, s_len):
                      s((8, 8, s_len, 64)), s((8,), jnp.int32))
 
 
+@pytest.mark.parametrize("hkv,d", [(8, 64), (2, 64), (8, 128)],
+                         ids=["tp1", "tp4", "hd128"])
+def test_paged_flash_decode_compiles_at_serving_shapes(one_chip, hkv, d):
+    """The benchmark's paged decode: 32 slots, 2800 pages of 16, 256 pages
+    a slot, 32q/8kv x 64 (a 64-wide head read lane-dense); at tp=4 each
+    rank holds 2 KV heads; and a 128-wide head."""
+    from repro.kernels import flash_decode as fd
+
+    def s(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    pool = s((2800, hkv, *fd.page_rows(16, d)))
+    _kernel_compiles(fd.paged_flash_decode, s((32, 4 * hkv, d)), pool, pool,
+                     s((32, 256), jnp.int32), s((32,), jnp.int32))
+
+
 @pytest.mark.parametrize("b,l", [(1, 2048), (4, 512)])
 def test_flash_attention_compiles(one_chip, b, l):
     from repro.kernels import flash_attention as fa
@@ -108,7 +125,8 @@ def test_ring_protocols_compile_at_tp4_widths(tp_mesh, monkeypatch, op,
 def test_paged_decode_step_compiles_with_pallas(topo, monkeypatch):
     """One paged-decode step of granite-3-2b at full width and depth 2,
     bf16, with the kernels steered to the chip's path: the compiled step
-    holds the Pallas flash-decode kernel."""
+    holds the paged flash-decode kernel and no whole-table gather (no
+    instruction under the ``paged_gather`` scope)."""
     import dataclasses
 
     from repro.configs import get_config
@@ -137,5 +155,8 @@ def test_paged_decode_step_compiles_with_pallas(topo, monkeypatch):
             shapes[i], specs, is_leaf=lambda x: isinstance(x, P))
     shapes[2:] = [jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=rep)
                   for s in shapes[2:]]
-    compiled = built.fn.lower(*shapes).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = built.fn.lower(*shapes).compile().as_text()
+    assert "tpu_custom_call" in text
+    names = re.findall(r'op_name="([^"]*)"', text)
+    assert any("/attn/flash_decode/" in n for n in names)
+    assert not any("/paged_gather/" in n for n in names)

@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 import proptest as pt
+from repro.kernels import flash_decode as fd
 from repro.kernels import ops, ref
 
 R = np.random.RandomState(42)
@@ -129,6 +130,64 @@ def test_flash_decode_empty_shard_drops_out_of_combine():
                                    jnp.stack([lse for _, lse in parts]))
     want, _ = ref.flash_decode(q, k, v, length=jnp.asarray(lens))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=2e-4)
+
+
+# ------------------------------------------------------ paged flash decode
+PAGE = 16
+
+
+def _paged_case(hq, hkv, d, dtype, garbage):
+    """A pool of scattered pages (page 0 the scratch page), one slot per
+    length: 1, 15, 16, 17 tokens, a ragged 45 and a full table. Returns
+    (q, table, lengths, clean pools, dirty pools): the dirty pools hold
+    ``garbage`` on page 0 and past each slot's length in its last page,
+    the clean ones zeros there."""
+    pages_per_slot = 6
+    lens = np.asarray([1, 15, 16, 17, 45, pages_per_slot * PAGE], np.int32)
+    b = len(lens)
+    live = -(-lens // PAGE)
+    n_pages = 1 + int(live.sum()) + 5
+    ids = 1 + R.permutation(n_pages - 1)  # pages in no order, never page 0
+    table = np.zeros((b, pages_per_slot), np.int32)  # unused entries: page 0
+    dead = np.zeros((n_pages, PAGE), bool)
+    dead[0] = True
+    at = 0
+    for i, n in enumerate(live):
+        table[i, :n] = ids[at:at + n]
+        at += n
+        dead[table[i, n - 1], lens[i] - (n - 1) * PAGE:] = True
+    q = _arr((b, hq, d))
+    pools = [R.randn(n_pages, hkv, PAGE, d).astype(np.float32)
+             for _ in range(2)]
+    mask = dead[:, None, :, None]
+    stored = (n_pages, hkv, *fd.page_rows(PAGE, d))  # the tokens in order
+    clean = [jnp.asarray(np.where(mask, 0.0, p).reshape(stored), dtype)
+             for p in pools]
+    dirty = [jnp.asarray(np.where(mask, garbage, p).reshape(stored), dtype)
+             for p in pools]
+    return q, jnp.asarray(table), jnp.asarray(lens), clean, dirty
+
+
+@pytest.mark.parametrize("garbage", [np.nan, 3e4], ids=["nan", "large"])
+@pytest.mark.parametrize("hq,hkv,d,dtype", [
+    (32, 8, 64, jnp.bfloat16), (32, 8, 128, jnp.float32),
+    (8, 2, 64, jnp.float32), (8, 2, 128, jnp.bfloat16)],
+    ids=["32-8x64-bf16", "32-8x128", "8-2x64", "8-2x128-bf16"])
+def test_paged_flash_decode_matches_gathered_reference(hq, hkv, d, dtype,
+                                                       garbage):
+    """The paged kernel, reading pages in place through the table, against
+    the whole-table gather plus ref.flash_decode. Whatever lies past a
+    slot's length (scratch page 0, the rest of its last page) must not
+    reach o or lse, so the reference reads zeros there and the kernel
+    garbage. Blocks of two pages: slots span several blocks, and a
+    slot's last block prefetches the next slot's first."""
+    q, table, lens, (ck, cv), (dk, dv) = _paged_case(hq, hkv, d, dtype,
+                                                     garbage)
+    og, lg = ops.paged_flash_decode(q, dk, dv, table, lens,
+                                    bkv=2 * PAGE, force="pallas")
+    ow, lw = ops.paged_flash_decode(q, ck, cv, table, lens, force="ref")
+    np.testing.assert_allclose(np.asarray(og), np.asarray(ow), atol=2e-5, rtol=2e-4)
+    np.testing.assert_allclose(np.asarray(lg), np.asarray(lw), atol=2e-5, rtol=2e-5)
 
 
 # --------------------------------------------------------------- ssd scan

@@ -32,23 +32,24 @@ def _engine(mesh):
 
 def _serve(eng):
     """Serve PROMPTS to the end; returns (tokens per request, the plan of
-    each working step)."""
+    each working step, the cached lengths each plan was made at)."""
     reqs = [Request(prompt=list(p), max_new_tokens=NEW_TOKENS)
             for p in PROMPTS]
     for r in reqs:
         assert eng.add(r)
-    plans = []
+    plans, lens = [], []
     plan = eng.sched.plan
 
     def recording_plan():
         plans.append(plan())
+        lens.append(eng.kv.lens.copy())
         return plans[-1]
 
     eng.sched.plan = recording_plan
     while eng.step():
         pass
     assert all(r.done for r in reqs)
-    return [r.out_tokens for r in reqs], plans
+    return [r.out_tokens for r in reqs], plans, lens
 
 
 @pytest.fixture(scope="module")
@@ -57,11 +58,11 @@ def served(one_device_mesh, tmp_path_factory):
     it; the second's ``serve.*`` host events, read back from the capture."""
     from jax.profiler import ProfileData
 
-    plain, _ = _serve(_engine(one_device_mesh))
+    plain, _, _ = _serve(_engine(one_device_mesh))
     eng = _engine(one_device_mesh)
     log_dir = str(tmp_path_factory.mktemp("profile"))
     with jax.profiler.trace(log_dir):
-        traced, plans = _serve(eng)
+        traced, plans, lens = _serve(eng)
     path, = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
                       recursive=True)
     events = []
@@ -70,7 +71,7 @@ def served(one_device_mesh, tmp_path_factory):
             events += [(ev.name, ev.start_ns, ev.end_ns, dict(ev.stats))
                        for ev in line.events if ev.name.startswith("serve.")]
     events.sort(key=lambda e: e[1])
-    return {"engine": eng, "plans": plans, "events": events,
+    return {"engine": eng, "plans": plans, "lens": lens, "events": events,
             "plain": plain, "traced": traced}
 
 
@@ -103,6 +104,18 @@ def test_span_counters_are_the_engines_counts(served):
     assert [e[3]["slots"] for e in steps][0] == 2  # both slots admitted
     assert max(e[3]["queue"] for e in steps) == len(PROMPTS) - 2
     assert steps[-1][3]["queue"] == 0 and all(e[3]["pages"] > 0 for e in steps)
+
+
+def test_decode_pages_counter_is_what_the_kernel_reads(served):
+    """``pages`` on ``serve.decode``: per decoding slot, the pages that
+    hold its cached tokens and the new one."""
+    page = served["engine"].kv.page_size
+    per_slot = [[-(-(int(lens[i]) + 1) // page) for i in p.decode]
+                for p, lens in zip(served["plans"], served["lens"])
+                if p.decode]
+    got = [e[3]["pages"] for e in _named(served, "serve.decode")]
+    assert got == [sum(n) for n in per_slot]
+    assert max(map(max, per_slot)) > 1  # some slot reads past its first page
 
 
 def _inside(inner, outer):
