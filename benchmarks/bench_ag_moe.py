@@ -38,7 +38,7 @@ def rows():
         cap = max(8, t_loc * k // e * 2)
 
         def expert_fn(tok, lg):
-            dsp, info = mo.topk_dispatch(tok, lg, k, cap)
+            dsp, info = mo.dispatch(tok, *mo.topk_route(lg, k), e, cap)
             y = ops.grouped_matmul(dsp, wi, out_dtype=tok.dtype)
             y = jax.nn.silu(y)
             y = ops.grouped_matmul(y, wo, out_dtype=tok.dtype)

@@ -30,7 +30,7 @@ wo = jnp.asarray(rng.randn(W * (E // W), DFF, D) / np.sqrt(DFF), jnp.float32)
 
 def moe_layer(x_loc, wi_loc, wo_loc):
     logits = x_loc @ router
-    disp, info = mo.topk_dispatch(x_loc, logits, K, CAP)  # local dispatch
+    disp, info = mo.dispatch(x_loc, *mo.topk_route(logits, K), E, CAP)  # local dispatch
     x_ep = mo.a2a_ep(disp, "ep", mode="one_shot")  # tokens -> their experts
     y = ops.grouped_matmul(x_ep, wi_loc, out_dtype=x_loc.dtype)
     y = jax.nn.silu(y)
@@ -50,7 +50,7 @@ print(f"in {x.shape} -> out {y.shape}; finite={bool(jnp.all(jnp.isfinite(y)))}")
 
 # oracle: same math on one device (experts unsharded)
 logits = x @ router
-disp, info = mo.topk_dispatch(x, logits, K, CAP * W)
+disp, info = mo.dispatch(x, *mo.topk_route(logits, K), E, CAP * W)
 yy = ops.grouped_matmul(disp, wi, out_dtype=x.dtype)
 yy = jax.nn.silu(yy)
 yy = ops.grouped_matmul(yy, wo, out_dtype=x.dtype)

@@ -1,6 +1,8 @@
 """granite-moe-3b-a800m [moe] — 40 experts top-8.
 
-[hf:ibm-granite/granite-3.0-3b-a800m-base family; hf]
+[hf:ibm-granite/granite-3.0-3b-a800m-base; hf] 32 layers, d 1536, 24q/8kv
+heads of 64, 40 routed experts of width 512 (gated SiLU), 8 per token,
+vocab 49155, tied embeddings.
 """
 from .base import ModelConfig
 
@@ -19,5 +21,5 @@ CONFIG = ModelConfig(
     activation="silu",
     gated_mlp=True,
     tie_embeddings=True,
-    source="hf:ibm-granite/granite-3.0-1b-a400m-base",
+    source="hf:ibm-granite/granite-3.0-3b-a800m-base",
 )

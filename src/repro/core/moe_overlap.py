@@ -39,19 +39,23 @@ class DispatchInfo(NamedTuple):
     weight: Array  # (T, k) combine weight (renormalized top-k prob, 0 if dropped)
 
 
-def topk_dispatch(x: Array, logits: Array, k: int, capacity: int):
-    """Capacity-based top-k dispatch.
-
-    x: (T, d), logits: (T, E) -> (dispatched (E, cap, d), DispatchInfo).
-    Tokens beyond an expert's capacity are dropped (weight 0) — standard
-    capacity-factor routing.
-    """
-    t, d = x.shape
-    e = logits.shape[-1]
+def topk_route(logits: Array, k: int):
+    """The router's choice: logits (T, E) -> (expert ids (T, k), their
+    softmax probabilities renormalised to sum to 1 (T, k)). A token's k
+    experts are distinct."""
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     top_p, top_e = lax.top_k(probs, k)  # (T, k)
-    top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    return top_e, top_p / jnp.sum(top_p, axis=-1, keepdims=True)
 
+
+def dispatch(x: Array, top_e: Array, top_p: Array, e: int, capacity: int):
+    """Capacity-based dispatch of the routed tokens x (T, d) into ``e``
+    expert buffers of ``capacity`` rows: (dispatched (E, cap, d),
+    DispatchInfo). A token past its expert's capacity is dropped
+    (weight 0), standard capacity-factor routing; a capacity of T drops
+    none."""
+    t, d = x.shape
+    k = top_e.shape[1]
     onehot = jax.nn.one_hot(top_e, e, dtype=jnp.int32)  # (T, k, E)
     flat = onehot.reshape(t * k, e)  # slot-major: token 0 slot 0, token 0 slot 1, ...
     pos_in_expert = jnp.cumsum(flat, axis=0) - flat  # (T*k, E)

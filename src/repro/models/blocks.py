@@ -666,20 +666,71 @@ def _expert_ffn(cfg, x_disp: Array, wi: Array, wo: Array) -> Array:
     return ops.grouped_matmul(y, wo, out_dtype=x_disp.dtype)
 
 
-def _capacity(t: int, k: int, e: int, factor: float) -> int:
+def _capacity(t: int, k: int, e: int, factor: Optional[float]) -> int:
+    """Rows of each expert's buffer for ``t`` tokens: ``factor`` times an
+    even share of the t*k routed rows, or, with no factor, t: a token
+    picks k distinct experts, so no buffer can overflow (dropless)."""
+    if factor is None:
+        return t
     cap = int(math.ceil(t * k / e * factor))
     return max(8, ((cap + 7) // 8) * 8)
 
 
-def moe_train(cfg, pcfg, info, p: dict, x_sp: Array) -> Array:
+def _route_dispatch(h: Array, logits: Array, k: int, cap: int):
+    """Scopes ``router`` (softmax, top-k) and ``dispatch`` (the scatter
+    into the (E, cap, d) buffers)."""
+    with obs.scope("router"):
+        top_e, top_p = mo.topk_route(logits, k)
+    with obs.scope("dispatch"):
+        return mo.dispatch(h, top_e, top_p, logits.shape[-1], cap)
+
+
+def _router_logits(cfg, p: dict, x: Array, dt) -> Tuple[Array, Array]:
+    """(normed tokens (T, d), router logits (T, E)) under ``router``."""
+    with obs.scope("router"):
+        h = rmsnorm(x, p["ln"].astype(dt), cfg.norm_eps).reshape(-1, x.shape[-1])
+        return h, local_linear(h, p["router"].astype(dt))
+
+
+def _local_experts(cfg, h, logits, wi, wo, k: int, cap: int, dt) -> Array:
+    """Every expert on this rank, no exchange: route, dispatch, the
+    grouped GEMMs (``experts``), and the weighted gather back
+    (``combine``)."""
+    disp, dinfo = _route_dispatch(h, logits, k, cap)
+    with obs.scope("experts"):
+        y = _expert_ffn(cfg, disp, wi, wo)
+    with obs.scope("combine"):
+        return mo.topk_combine(y, dinfo, out_dtype=dt)
+
+
+def _ep_experts(cfg, pcfg, h, logits, wi, wo, k: int, cap: int, dt) -> Array:
+    """Experts sharded over the model axis: the AllToAll out rides in
+    ``dispatch``, the one back in ``combine``."""
+    a2a = pcfg.policy.resolve("a2a_ep")
+    disp, dinfo = _route_dispatch(h, logits, k, cap)
+    with obs.scope("dispatch"):
+        x_ep = mo.a2a_ep(disp, MODEL_AXIS, mode=a2a.mode,
+                         backend=a2a.backend, wire=a2a.wire)
+    with obs.scope("experts"):
+        y_ep = _expert_ffn(cfg, x_ep, wi, wo)  # (E_loc, tp*cap, D)
+    with obs.scope("combine"):
+        back = mo.a2a_ep_inverse(y_ep, MODEL_AXIS, mode=a2a.mode,
+                                 backend=a2a.backend, wire=a2a.wire)
+        return mo.topk_combine(back, dinfo, out_dtype=dt)
+
+
+def moe_train(cfg, pcfg, info, p: dict, x_sp: Array,
+              factor: Optional[float]) -> Array:
+    """The routed-expert block over a sequence. Each expert's buffer is
+    sized by ``_capacity`` from the tokens dispatched together and
+    ``factor``: training passes ``cfg.capacity_factor``, the serving
+    prefill programs None (dropless)."""
     with obs.scope("moe"):
         b, s_loc, d = x_sp.shape
         tp = pcfg.tp
         dt = x_sp.dtype
-        ln, router = p["ln"].astype(dt), p["router"].astype(dt)
         wi, wo = p["wi"].astype(dt), p["wo"].astype(dt)
-        h = rmsnorm(x_sp, ln, cfg.norm_eps).reshape(b * s_loc, d)
-        logits = local_linear(h, router)  # (T_loc, E)
+        h, logits = _router_logits(cfg, p, x_sp, dt)  # (T_loc, d), (T_loc, E)
         k = cfg.experts_per_token
 
         if info.moe_mode == "ep" and tp > 1:
@@ -690,18 +741,10 @@ def moe_train(cfg, pcfg, info, p: dict, x_sp: Array) -> Array:
             while t_loc % n_chunks != 0:
                 n_chunks -= 1
             t_c = t_loc // n_chunks
-            cap = _capacity(t_c, k, cfg.num_experts, cfg.capacity_factor)
-
-            a2a = pcfg.policy.resolve("a2a_ep")
+            cap = _capacity(t_c, k, cfg.num_experts, factor)
 
             def ep_chunk(hc, lc):
-                disp, dinfo = mo.topk_dispatch(hc, lc, k, cap)  # (E, cap, D)
-                x_ep = mo.a2a_ep(disp, MODEL_AXIS, mode=a2a.mode,
-                                 backend=a2a.backend, wire=a2a.wire)
-                y_ep = _expert_ffn(cfg, x_ep, wi, wo)  # (E_loc, tp*cap, D)
-                back = mo.a2a_ep_inverse(y_ep, MODEL_AXIS, mode=a2a.mode,
-                                         backend=a2a.backend, wire=a2a.wire)
-                return mo.topk_combine(back, dinfo, out_dtype=dt)
+                return _ep_experts(cfg, pcfg, hc, lc, wi, wo, k, cap, dt)
 
             if pcfg.remat != "none":
                 ep_chunk = jax.checkpoint(ep_chunk)
@@ -716,12 +759,12 @@ def moe_train(cfg, pcfg, info, p: dict, x_sp: Array) -> Array:
         # TP mode: AllGather token chunks around the ring, run the d_ff-sharded
         # experts per chunk (AG+MoE), then ring-ReduceScatter the partial
         # outputs (MoE+RS). (EP configs on tp=1 meshes also land here.)
-        cap = _capacity(h.shape[0], k, cfg.num_experts, cfg.capacity_factor)
-
         def expert_fn(tokens, tok_logits):
-            dsp, dinfo = mo.topk_dispatch(tokens, tok_logits, k, cap)
-            y = _expert_ffn(cfg, dsp, wi, wo)
-            return mo.topk_combine(y, dinfo, out_dtype=tokens.dtype)
+            # the tokens this call is handed: a ring chunk, or every
+            # rank's when the gather is monolithic
+            cap = _capacity(tokens.shape[0], k, cfg.num_experts, factor)
+            return _local_experts(cfg, tokens, tok_logits, wi, wo, k, cap,
+                                  tokens.dtype)
 
         if pcfg.remat != "none":
             # per-ring-chunk checkpoint: the backward live-set is one chunk's
@@ -737,36 +780,31 @@ def moe_train(cfg, pcfg, info, p: dict, x_sp: Array) -> Array:
             full = mo.ag_moe(h, logits, expert_fn, MODEL_AXIS,
                              mode=ag.mode, backend=ag.backend)
             rs = pcfg.policy.resolve("reduce_scatter")
-            out = cm.reduce_scatter_chunked(full, MODEL_AXIS, mode=rs.mode,
-                                            backend=rs.backend, wire=rs.wire)
+            with obs.scope("combine"):
+                out = cm.reduce_scatter_chunked(full, MODEL_AXIS, mode=rs.mode,
+                                                backend=rs.backend, wire=rs.wire)
         else:
             out = expert_fn(h, logits)
         return x_sp + out.reshape(b, s_loc, d)
 
 
 def moe_decode(cfg, pcfg, info, p: dict, x: Array) -> Array:
+    """The routed-expert block for decode tokens, dropless: each expert's
+    buffer holds every token of the call (serving only)."""
     with obs.scope("moe"):
         b, t, d = x.shape
         dt = x.dtype
-        ln, router = p["ln"].astype(dt), p["router"].astype(dt)
         wi, wo = p["wi"].astype(dt), p["wo"].astype(dt)
-        h = rmsnorm(x, ln, cfg.norm_eps).reshape(b * t, d)
-        logits = local_linear(h, router)
+        h, logits = _router_logits(cfg, p, x, dt)
         k = cfg.experts_per_token
-        cap = _capacity(h.shape[0], k, cfg.num_experts, cfg.capacity_factor)
-        disp, dinfo = mo.topk_dispatch(h, logits, k, cap)
+        cap = _capacity(h.shape[0], k, cfg.num_experts, None)
         if info.moe_mode == "ep" and pcfg.tp > 1:
-            a2a = pcfg.policy.resolve("a2a_ep")
-            x_ep = mo.a2a_ep(disp, MODEL_AXIS, mode=a2a.mode,
-                             backend=a2a.backend, wire=a2a.wire)
-            y_ep = _expert_ffn(cfg, x_ep, wi, wo)
-            back = mo.a2a_ep_inverse(y_ep, MODEL_AXIS, mode=a2a.mode,
-                                     backend=a2a.backend, wire=a2a.wire)
-            out = mo.topk_combine(back, dinfo, out_dtype=dt)
+            out = _ep_experts(cfg, pcfg, h, logits, wi, wo, k, cap, dt)
         else:
-            y = _expert_ffn(cfg, disp, wi, wo)
-            out = mo.topk_combine(y, dinfo, out_dtype=dt)
-            out = psum_tp(out, pcfg) if info.moe_mode == "tp" else out
+            out = _local_experts(cfg, h, logits, wi, wo, k, cap, dt)
+            if info.moe_mode == "tp":
+                with obs.scope("combine"):
+                    out = psum_tp(out, pcfg)
         return x + out.reshape(b, t, d)
 
 

@@ -179,7 +179,8 @@ class LM:
             lambda p_, h_: blocks.attention_train(cfg, pcfg, info, p_, h_)
         )
         mlp = self._ckpt(lambda p_, h_: blocks.mlp_train(cfg, pcfg, info, p_, h_))
-        moe = self._ckpt(lambda p_, h_: blocks.moe_train(cfg, pcfg, info, p_, h_))
+        moe = self._ckpt(lambda p_, h_: blocks.moe_train(
+            cfg, pcfg, info, p_, h_, cfg.capacity_factor))
         ssm = self._ckpt(lambda p_, h_: blocks.ssm_train(cfg, pcfg, info, p_, h_))
         cross = self._ckpt(
             lambda p_, h_, src: blocks.attention_train(
@@ -354,7 +355,7 @@ class LM:
                 cfg, pcfg, info, pl["attn"], carry, return_kv=True
             )
             if cfg.family == "moe":
-                hh = blocks.moe_train(cfg, pcfg, info, pl["ffn"], hh)
+                hh = blocks.moe_train(cfg, pcfg, info, pl["ffn"], hh, None)
             else:
                 hh = blocks.mlp_train(cfg, pcfg, info, pl["ffn"], hh)
             pad = s_max - k.shape[2]
@@ -533,7 +534,7 @@ class LM:
             hh, pk, pv = blocks.attention_prefill_chunk(
                 cfg, pcfg, info, pl["attn"], carry, pk, pv, row, start, n_valid)
             if cfg.family == "moe":
-                hh = blocks.moe_train(cfg, pcfg, info, pl["ffn"], hh)
+                hh = blocks.moe_train(cfg, pcfg, info, pl["ffn"], hh, None)
             else:
                 hh = blocks.mlp_train(cfg, pcfg, info, pl["ffn"], hh)
             return hh, (pk, pv)
@@ -623,7 +624,7 @@ class LM:
                 n_valid, rows_own, inv_perm, placement=placement,
                 cp_attend=cp_attend)
             if cfg.family == "moe":
-                hh = blocks.moe_train(cfg, pcfg, info, pl["ffn"], hh)
+                hh = blocks.moe_train(cfg, pcfg, info, pl["ffn"], hh, None)
             else:
                 hh = blocks.mlp_train(cfg, pcfg, info, pl["ffn"], hh)
             return hh, (pk, pv)

@@ -49,7 +49,15 @@ name                        kind   meaning (counters)
                                    ``flash_decode`` (decode) or
                                    ``chunk_attend`` (prefill), ``out``
                                    (output projection and its reduction)
-``mlp`` / ``moe``           scope  the feed-forward block
+``mlp`` / ``moe``           scope  the feed-forward block; ``moe``'s children
+                                   ``router`` (norm, router logits, softmax
+                                   and top-k), ``dispatch`` (the scatter
+                                   into the experts' buffers, and an
+                                   expert-parallel AllToAll out),
+                                   ``experts`` (the grouped GEMMs and their
+                                   activation), ``combine`` (the weighted
+                                   gather back, the AllToAll home or the
+                                   reduction over tensor-parallel ranks)
 ``logits``                  scope  final norm and unembedding
 ==========================  =====  ==========================================
 

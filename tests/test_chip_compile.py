@@ -122,6 +122,22 @@ def test_ring_protocols_compile_at_tp4_widths(tp_mesh, monkeypatch, op,
     _kernel_compiles(fn, *args)
 
 
+def _compiled_step(built, mesh) -> str:
+    """The HLO of a built serving step compiled for the described chips:
+    parameters and pools placed by its specs, the host inputs
+    replicated."""
+    rep = NamedSharding(mesh, P())
+    shapes = list(built.in_shapes)
+    for i, specs in enumerate(built.in_pspecs):
+        shapes[i] = jax.tree.map(
+            lambda s, sp: jax.ShapeDtypeStruct(
+                s.shape, s.dtype, sharding=NamedSharding(mesh, sp)),
+            shapes[i], specs, is_leaf=lambda x: isinstance(x, P))
+    shapes[2:] = [jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=rep)
+                  for s in shapes[2:]]
+    return built.fn.lower(*shapes).compile().as_text()
+
+
 def test_paged_decode_step_compiles_with_pallas(topo, monkeypatch):
     """One paged-decode step of granite-3-2b at full width and depth 2,
     bf16, with the kernels steered to the chip's path: the compiled step
@@ -146,17 +162,46 @@ def test_paged_decode_step_compiles_with_pallas(topo, monkeypatch):
                                kind="decode"),
         mesh, num_pages=batch * max_len // page + 1, page_size=page,
         pages_per_slot=max_len // page, cache_dtype=jnp.bfloat16)
-    rep = NamedSharding(mesh, P())
-    shapes = list(built.in_shapes)
-    for i, specs in enumerate(built.in_pspecs):
-        shapes[i] = jax.tree.map(
-            lambda s, sp: jax.ShapeDtypeStruct(
-                s.shape, s.dtype, sharding=NamedSharding(mesh, sp)),
-            shapes[i], specs, is_leaf=lambda x: isinstance(x, P))
-    shapes[2:] = [jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=rep)
-                  for s in shapes[2:]]
-    text = built.fn.lower(*shapes).compile().as_text()
+    text = _compiled_step(built, mesh)
     assert "tpu_custom_call" in text
     names = re.findall(r'op_name="([^"]*)"', text)
     assert any("/attn/flash_decode/" in n for n in names)
     assert not any("/paged_gather/" in n for n in names)
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill"])
+def test_moe_paged_programs_compile_with_pallas(topo, monkeypatch, kind):
+    """granite-moe-3b-a800m at full width and depth 2, bf16, Pallas on:
+    the benchmark's paged decode (32 slots) and 256-token chunked
+    prefill compile for a v5e, each holding the experts' grouped-GEMM
+    kernels under the ``moe/experts`` scope."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.configs.base import ParallelConfig, ShapeConfig
+    from repro.kernels import ops as kops
+    from repro.launch.steps import (build_paged_decode_step,
+                                    build_prefill_chunk_step)
+
+    monkeypatch.setattr(kops, "_platform", lambda: "tpu")
+    cfg = dataclasses.replace(get_config("granite-moe-3b-a800m"), num_layers=2)
+    pcfg = ParallelConfig(dp=1, tp=1, param_dtype="bfloat16",
+                          compute_dtype="bfloat16")
+    mesh = Mesh(np.asarray(topo.devices[:1]).reshape(1, 1),
+                ("data", "model"))
+    batch, max_len, page, pages = 32, 4096, 16, 4000
+    geometry = dict(num_pages=pages, page_size=page,
+                    pages_per_slot=max_len // page, cache_dtype=jnp.bfloat16)
+    if kind == "decode":
+        built = build_paged_decode_step(
+            cfg, pcfg, ShapeConfig("serve", seq_len=max_len,
+                                   global_batch=batch, kind="decode"),
+            mesh, **geometry)
+    else:
+        built = build_prefill_chunk_step(cfg, pcfg, mesh, chunk=256,
+                                         n_streams=1, **geometry)
+    text = _compiled_step(built, mesh)
+    kernels = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and "/moe/" in line]
+    assert sum(bool(re.search(r"/moe/(checkpoint/)?experts/", line))
+               for line in kernels) == 2

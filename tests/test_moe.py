@@ -20,7 +20,7 @@ def test_dispatch_combine_identity(t, e, k):
     d = 16
     x = jnp.asarray(R.randn(t, d), jnp.float32)
     logits = jnp.asarray(R.randn(t, e), jnp.float32)
-    disp, info = mo.topk_dispatch(x, logits, k, capacity=t * k)
+    disp, info = mo.dispatch(x, *mo.topk_route(logits, k), e, t * k)
     y = mo.topk_combine(disp, info)
     np.testing.assert_allclose(np.asarray(y), np.asarray(x), atol=1e-5, rtol=1e-5)
 
@@ -30,7 +30,7 @@ def test_dispatch_respects_capacity(t, e):
     d, k, cap = 8, 2, 8
     x = jnp.asarray(R.randn(t, d), jnp.float32)
     logits = jnp.asarray(R.randn(t, e), jnp.float32)
-    disp, info = mo.topk_dispatch(x, logits, k, capacity=cap)
+    disp, info = mo.dispatch(x, *mo.topk_route(logits, k), e, cap)
     assert disp.shape == (e, cap, d)
     assert bool(jnp.all(info.position < cap))
     # weights of kept slots are positive, dropped slots zero, all finite
@@ -46,7 +46,7 @@ def test_dispatch_slot_uniqueness(t, e, k):
     x = jnp.asarray(R.randn(t, d), jnp.float32)
     logits = jnp.asarray(R.randn(t, e), jnp.float32)
     cap = t * k
-    disp, info = mo.topk_dispatch(x, logits, k, cap)
+    disp, info = mo.dispatch(x, *mo.topk_route(logits, k), e, cap)
     kept = np.asarray(info.weight).reshape(-1) > 0
     pairs = np.stack([np.asarray(info.expert).reshape(-1),
                       np.asarray(info.position).reshape(-1)], 1)[kept]
@@ -57,5 +57,5 @@ def test_combine_weights_sum_to_one():
     t, e, k, d = 32, 8, 4, 8
     x = jnp.asarray(R.randn(t, d), jnp.float32)
     logits = jnp.asarray(R.randn(t, e), jnp.float32)
-    _, info = mo.topk_dispatch(x, logits, k, capacity=t * k)
+    _, info = mo.dispatch(x, *mo.topk_route(logits, k), e, t * k)
     np.testing.assert_allclose(np.asarray(info.weight.sum(-1)), 1.0, atol=1e-5)

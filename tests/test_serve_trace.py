@@ -20,14 +20,13 @@ PROMPTS = [[11, 7, 23, 4, 19, 3, 8, 2, 5], [9, 8, 7], [1, 2, 3, 4, 5, 6],
 NEW_TOKENS = 4
 
 
-def _engine(mesh):
+def _engine(mesh, arch="granite-3-2b"):
     from repro.launch.serve import build_paged_engine
     from repro.serve import ServeConfig
 
     scfg = ServeConfig(batch=2, max_len=32, page_size=8, chunk=4,
                        token_budget=6)
-    return build_paged_engine(reduced(ARCHS["granite-3-2b"]), PCFG, scfg,
-                              mesh)
+    return build_paged_engine(reduced(ARCHS[arch]), PCFG, scfg, mesh)
 
 
 def _serve(eng):
@@ -148,10 +147,8 @@ def test_greedy_tokens_are_the_same_under_the_profiler(served):
 # scopes in the compiled programs
 
 
-@pytest.fixture(scope="module")
-def programs(one_device_mesh):
-    """The compiled HLO text of both paged programs."""
-    eng = _engine(one_device_mesh)
+def _compiled(eng):
+    """The compiled HLO text of the engine's two paged programs."""
     b, p = eng.scfg.batch, eng.kv.pages_per_slot
     i32 = jnp.int32
     decode = eng.decode_fn.lower(
@@ -162,6 +159,12 @@ def programs(one_device_mesh):
         jnp.zeros((1,), i32), jnp.zeros((1, eng.scfg.chunk), i32))
     return {"decode": decode.compile().as_text(),
             "prefill": prefill.compile().as_text()}
+
+
+@pytest.fixture(scope="module")
+def programs(one_device_mesh):
+    """The compiled HLO text of both paged programs."""
+    return _compiled(_engine(one_device_mesh))
 
 
 ATTN = "layers/while/body/closed_call/{}attn/"
@@ -205,19 +208,18 @@ def test_scan_slicing_lies_outside_the_layer_body(programs):
     assert not any(re.search(r"/(attn|mlp|moe)/", n) for n in own)
 
 
-def test_moe_blocks_carry_their_scope(one_device_mesh):
-    from repro.models import build_model
+# the prefill body and its expert computation run under jax.checkpoint
+MOE = r"layers/while/body/closed_call/(checkpoint/)?moe/(checkpoint/)?{}/"
 
-    cfg = reduced(ARCHS["granite-moe-3b-a800m"])
-    model = build_model(cfg, PCFG)
-    params, _ = model.init(jax.random.PRNGKey(0), jnp.float32)
-    shapes = model.paged_cache_shapes(4, 8, jnp.float32)
-    pools = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes)
-    text = jax.jit(jax.shard_map(
-        model.decode_step_paged_local, mesh=one_device_mesh,
-        in_specs=jax.sharding.PartitionSpec(), out_specs=(
-            jax.sharding.PartitionSpec(), jax.sharding.PartitionSpec()),
-        check_vma=False)).lower(
-        params, pools, jnp.zeros((2, 4), jnp.int32), jnp.zeros((2,), jnp.int32),
-        jnp.ones((2,), bool), jnp.zeros((2, 1), jnp.int32)).compile().as_text()
-    assert "layers/while/body/closed_call/moe/" in text
+
+def test_moe_blocks_carry_their_scope(one_device_mesh):
+    """Both paged programs of a routed-expert model mark the block
+    ``moe`` and its four parts: the router, the dispatch into the expert
+    buffers, the experts' grouped GEMMs and the combine."""
+    programs = _compiled(_engine(one_device_mesh, "granite-moe-3b-a800m"))
+    for kind, module in MODULES.items():
+        prefix = re.escape(f"jit({module[len('jit_'):]})/")
+        names = re.findall(r'op_name="([^"]*)"', programs[kind])
+        for part in ("router", "dispatch", "experts", "combine"):
+            assert any(re.match(prefix + MOE.format(part), n)
+                       for n in names), (kind, part)
