@@ -16,7 +16,8 @@ prefetch: tiles wholly past a slot's length are neither fetched (the
 index map repeats the last live tile) nor computed.
 
 :func:`paged_flash_decode` reads a page pool in place through per-slot
-block tables (serve/kvcache.py), copying each live page itself. The pool
+block tables (serve/kvcache.py), copying each live page itself, from the
+layer it is given of pools stacked over layers. The pool
 stores a page lane-dense (:func:`page_rows`): with D = 64, one 128-lane
 row holds two consecutive tokens, so each query head folds as two rows
 of queries, one per lane half (``parts``), and the parts merge by their
@@ -202,6 +203,7 @@ def flash_decode(
 def _paged_kernel(
     len_ref,
     table_ref,
+    layer_ref,
     q_ref,
     pool_k,
     pool_v,
@@ -232,15 +234,15 @@ def _paged_kernel(
 
     def copies(bb, j, buf, start: bool):
         """Start (or wait for) the copies of slot ``bb``'s block ``j``
-        into buffer ``buf``: one DMA per live page, all KV heads at once
-        (a page is contiguous across them)."""
+        into buffer ``buf``: one DMA per live page of the layer, all KV
+        heads at once (a page is contiguous across them)."""
         n = jnp.clip(live_pages(bb) - j * block_pages, 0, block_pages)
 
         def page(i, carry):
             src = table_ref[bb * pages_per_slot + j * block_pages + i]
             for pool, dst, kv in ((pool_k, k_buf, 0), (pool_v, v_buf, 1)):
-                dma = pltpu.make_async_copy(pool.at[src], dst.at[buf, i],
-                                            sems.at[kv, buf])
+                dma = pltpu.make_async_copy(pool.at[layer_ref[0], src],
+                                            dst.at[buf, i], sems.at[kv, buf])
                 dma.start() if start else dma.wait()
             return carry
 
@@ -299,10 +301,11 @@ def _paged_kernel(
 
 def paged_flash_decode(
     q: jax.Array,  # (B, Hq, D)
-    pool_k: jax.Array,  # (num_pages, Hkv, rows, width): page_rows
+    pool_k: jax.Array,  # (L, num_pages, Hkv, rows, width): page_rows
     pool_v: jax.Array,
     table: jax.Array,  # (B, P) int32 page ids
     length: jax.Array,  # (B,) int32 valid KV length
+    layer: jax.Array,  # () int32: the layer of the stacked pools to read
     *,
     scale: float | None = None,
     bkv: int = 512,
@@ -317,9 +320,12 @@ def paged_flash_decode(
     next slot's first, after a slot's last) run while this one is folded.
     Pages past ``ceil(length / page_size)`` are neither copied nor
     computed; table entries there may point anywhere in the pool.
+
+    The pools are stacked over layers and read at ``layer``, so a layer
+    loop that carries them reads each layer's pages where they lie.
     """
     b, hq, d = q.shape
-    _, hkv, rows, width = pool_k.shape
+    _, _, hkv, rows, width = pool_k.shape
     pages_per_slot = table.shape[1]
     group = hq // hkv
     parts = width // d
@@ -338,7 +344,7 @@ def paged_flash_decode(
                     jnp.eye(parts, dtype=jnp.float32))
     qp = qp.reshape(b, hkv, parts * group, width)
 
-    def slot_map(bb, lens, table):
+    def slot_map(bb, lens, table, layer):
         return (bb, 0, 0, 0)
 
     buf = pltpu.VMEM((2, block_pages, hkv, rows, width), pool_k.dtype)
@@ -346,7 +352,7 @@ def paged_flash_decode(
     o, lse = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(b,),
             in_specs=[
                 pl.BlockSpec((1, hkv, parts * group, width), slot_map),
@@ -376,6 +382,6 @@ def paged_flash_decode(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
-    )(length.astype(jnp.int32), table.astype(jnp.int32).reshape(-1), qp,
-      pool_k, pool_v)
+    )(length.astype(jnp.int32), table.astype(jnp.int32).reshape(-1),
+      jnp.asarray(layer, jnp.int32).reshape(1), qp, pool_k, pool_v)
     return o.reshape(b, hq, d), lse[..., 0].reshape(b, hq)

@@ -98,13 +98,14 @@ def flash_decode(q, k, v, length, *, scale=None, bkv=512, force: Force = None):
                             interpret=_interpret())
 
 
-def paged_flash_decode(q, pool_k, pool_v, table, length, *, scale=None,
-                       bkv=512, force: Force = None):
-    """Flash decode over a page pool through per-slot block tables."""
+def paged_flash_decode(q, pool_k, pool_v, table, length, layer, *,
+                       scale=None, bkv=512, force: Force = None):
+    """Flash decode through per-slot block tables over one layer of page
+    pools stacked over layers."""
     if not use_pallas(force):
         return _ref.paged_flash_decode(q, pool_k, pool_v, table, length,
-                                       scale=scale)
-    return _fd.paged_flash_decode(q, pool_k, pool_v, table, length,
+                                       layer, scale=scale)
+    return _fd.paged_flash_decode(q, pool_k, pool_v, table, length, layer,
                                   scale=scale, bkv=bkv,
                                   interpret=_interpret())
 

@@ -127,24 +127,26 @@ def flash_decode(
     return o, lse
 
 
-def gather_pages(pool: jax.Array, table: jax.Array,
+def gather_pages(pool: jax.Array, layer: jax.Array, table: jax.Array,
                  head_dim: int) -> jax.Array:
-    """Per-slot K/V copied out of a page pool: pool (num_pages, H, rows,
-    width), each head's page its tokens in order (kernels.flash_decode.
-    page_rows), table (B, P) page ids -> (B, H, P * page_size, head_dim)."""
-    _, h, rows, width = pool.shape
+    """Per-slot K/V of one layer copied out of pools stacked over layers:
+    pool (L, num_pages, H, rows, width), each head's page its tokens in
+    order (kernels.flash_decode.page_rows), read at ``layer`` through
+    table (B, P) page ids -> (B, H, P * page_size, head_dim)."""
+    _, _, h, rows, width = pool.shape
     b, pcount = table.shape
     ps = rows * width // head_dim
-    g = pool[table].reshape(b, pcount, h, ps, head_dim)
+    g = pool[layer, table].reshape(b, pcount, h, ps, head_dim)
     return g.transpose(0, 2, 1, 3, 4).reshape(b, h, pcount * ps, head_dim)
 
 
-def paged_flash_decode(q, pool_k, pool_v, table, length, *, scale=None):
-    """flash_decode over each slot's pages, gathered whole (entries past
-    the length are masked out by it)."""
+def paged_flash_decode(q, pool_k, pool_v, table, length, layer, *,
+                       scale=None):
+    """flash_decode over each slot's pages of one layer, gathered whole
+    (entries past the length are masked out by it)."""
     d = q.shape[-1]
-    return flash_decode(q, gather_pages(pool_k, table, d),
-                        gather_pages(pool_v, table, d), scale=scale,
+    return flash_decode(q, gather_pages(pool_k, layer, table, d),
+                        gather_pages(pool_v, layer, table, d), scale=scale,
                         length=length)
 
 

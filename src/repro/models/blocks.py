@@ -319,41 +319,46 @@ def attention_decode(
 # ===========================================================================
 
 
-def _gather_pages(pool: Array, table: Array, hd: int) -> Array:
-    """Materialize per-slot KV from the page pool (``ref.gather_pages``):
-    (B, H, P*page_size, hd). Unallocated table entries point at scratch
-    page 0; callers mask those positions out by length."""
+def _gather_pages(pool: Array, layer: Array, table: Array, hd: int) -> Array:
+    """Materialize per-slot KV of one layer from the stacked page pool
+    (``ref.gather_pages``): (B, H, P*page_size, hd). Unallocated table
+    entries point at scratch page 0; callers mask those positions out by
+    length."""
     with obs.scope("paged_gather"):
-        return kref.gather_pages(pool, table, hd)
+        return kref.gather_pages(pool, layer, table, hd)
 
 
-def _write_pages(pool: Array, table: Array, start: Array, n_valid: Array,
-                 x: Array) -> Array:
-    """Write x (S, C, H, hd), C consecutive tokens of each of S streams:
-    token t of stream s lands at position ``start[s] + t`` of the pages its
-    block-table row ``table[s]`` lists, for ``t < n_valid[s]``.
+def _write_pages(pool: Array, layer: Array, table: Array, start: Array,
+                 n_valid: Array, x: Array) -> Array:
+    """Write x (S, C, H, hd), C consecutive tokens of each of S streams,
+    into layer ``layer`` of the stacked pool (L, num_pages, H, rows,
+    width): token t of stream s lands at position ``start[s] + t`` of the
+    pages its block-table row ``table[s]`` lists, for ``t < n_valid[s]``.
 
-    A pool row holds ``parts`` tokens (``page_rows``), so each row the
-    tokens touch is read, the tokens merged in and the row written back
-    whole: XLA runs a scatter of whole rows as one scatter, but expands
-    a scatter into part of a lane row into a loop over its updates.
-    Rows with nothing to write go to scratch page 0."""
+    Each page the tokens touch is read whole, the tokens merged in and
+    the page written back whole: a scatter indexed by (layer, page) whose
+    window is a whole page leaves the pool in its own layout, where one
+    indexed by a page's rows would have the compiler lay the pool out
+    again around it. Pages with nothing to write go to the layer's
+    scratch page 0, unchanged."""
     s, c, h, hd = x.shape
-    rows, width = pool.shape[2:]
+    rows, width = pool.shape[3:]
     parts = width // hd
-    n = (c + parts - 2) // parts + 1  # rows that c tokens can touch
-    row = start[:, None] // parts + jnp.arange(n)  # (S, n) in the sequence
-    tok = row[..., None] * parts + jnp.arange(parts) - start[:, None, None]
-    new = (tok >= 0) & (tok < n_valid[:, None, None])  # (S, n, parts)
-    slot_page = jnp.minimum(row // rows, table.shape[1] - 1)
+    ps = rows * parts  # tokens a page
+    n = (c + ps - 2) // ps + 1  # pages that c tokens can touch
+    seq_page = start[:, None] // ps + jnp.arange(n)  # (S, n) in the sequence
+    tok = (seq_page[..., None] * ps + jnp.arange(ps)
+           - start[:, None, None])  # (S, n, ps) index into the chunk
+    new = (tok >= 0) & (tok < n_valid[:, None, None])
+    slot_page = jnp.minimum(seq_page, table.shape[1] - 1)
     page = jnp.where(new.any(-1), jnp.take_along_axis(table, slot_page, 1), 0)
-    at = row % rows
-    old = pool[page, :, at, :].reshape(s, n, h, parts, hd)
+    old = pool[layer, page].reshape(s, n, h, rows, parts, hd)
     put = jnp.take_along_axis(
-        x, jnp.clip(tok, 0, c - 1).reshape(s, n * parts, 1, 1), 1)
-    put = put.reshape(s, n, parts, h, hd).transpose(0, 1, 3, 2, 4)
-    merged = jnp.where(new[:, :, None, :, None], put.astype(pool.dtype), old)
-    return pool.at[page, :, at, :].set(merged.reshape(s, n, h, width))
+        x, jnp.clip(tok, 0, c - 1).reshape(s, n * ps, 1, 1), 1)
+    put = put.reshape(s, n, rows, parts, h, hd).transpose(0, 1, 4, 2, 3, 5)
+    keep = new.reshape(s, n, 1, rows, parts, 1)
+    merged = jnp.where(keep, put.astype(pool.dtype), old)
+    return pool.at[layer, page].set(merged.reshape(s, n, h, rows, width))
 
 
 def attention_decode_paged(
@@ -362,16 +367,18 @@ def attention_decode_paged(
     info: TPInfo,
     p: dict,
     x: Array,        # (B, 1, D) replicated over tp
-    pool_k: Array,   # (num_pages, Hkv_loc, rows, width): page_rows
+    pool_k: Array,   # (L, num_pages, Hkv_loc, rows, width): page_rows
     pool_v: Array,
+    layer: Array,    # () int32: this layer's index into the pools
     table: Array,    # (B, P) int32 page ids
     lengths: Array,  # (B,) tokens already cached per slot
     active: Array,   # (B,) bool — idle lanes write to the scratch page
 ) -> Tuple[Array, Array, Array]:
-    """Decode-step attention against the paged KV pool: write this
-    token's K/V at each live slot's next position (routed through its
-    block table), then flash-decode over each slot's pages: read in
-    place by the paged kernel, or gathered whole on the reference path."""
+    """Decode-step attention against layer ``layer`` of the stacked paged
+    KV pools: write this token's K/V at each live slot's next position
+    (routed through its block table), then flash-decode over each slot's
+    pages: read in place by the paged kernel, or gathered whole on the
+    reference path."""
     b, _, d = x.shape
     hd = cfg.head_dim
     pp = _get_attn(p, x.dtype)
@@ -387,16 +394,19 @@ def attention_decode_paged(
                 k_new = rope(k_new[:, None], pos[:, None], cfg.rope_theta)[:, 0]
         with obs.scope("kv_write"):
             one = active.astype(jnp.int32)  # idle lanes write nothing
-            pool_k = _write_pages(pool_k, table, pos, one, k_new[:, None])
-            pool_v = _write_pages(pool_v, table, pos, one, v_new[:, None])
+            pool_k = _write_pages(pool_k, layer, table, pos, one,
+                                  k_new[:, None])
+            pool_v = _write_pages(pool_v, layer, table, pos, one,
+                                  v_new[:, None])
         eff = jnp.where(active, pos + 1, 1)
         if ops.use_pallas():
             # the kernel reads each slot's live pages in place
             with obs.scope("flash_decode"):
-                o, _ = ops.paged_flash_decode(q, pool_k, pool_v, table, eff)
+                o, _ = ops.paged_flash_decode(q, pool_k, pool_v, table, eff,
+                                              layer)
         else:
-            k_all = _gather_pages(pool_k, table, hd)
-            v_all = _gather_pages(pool_v, table, hd)
+            k_all = _gather_pages(pool_k, layer, table, hd)
+            v_all = _gather_pages(pool_v, layer, table, hd)
             with obs.scope("flash_decode"):
                 o, _ = ops.flash_decode(q, k_all, v_all, eff)
         with obs.scope("out"):
@@ -431,17 +441,19 @@ def attention_prefill_chunk(
     info: TPInfo,
     p: dict,
     x_sp: Array,       # (1, C_loc, D) — one request's chunk, SP over tp
-    pool_k: Array,     # (num_pages, Hkv_loc, rows, width): page_rows
+    pool_k: Array,     # (L, num_pages, Hkv_loc, rows, width): page_rows
     pool_v: Array,
+    layer: Array,      # () int32: this layer's index into the pools
     table_row: Array,  # (P,) int32 — the request's block table
     start: Array,      # scalar int32: absolute position of the chunk's 1st token
     n_valid: Array,    # scalar int32: real tokens in the chunk (rest padding)
 ) -> Tuple[Array, Array, Array]:
     """One chunked-prefill attention layer: AG+GEMM projections over the
-    chunk (resolves ag_matmul), chunk K/V written into the paged pool,
-    chunk queries attending over the pool (prefix + the chunk itself,
-    causal at absolute positions), GEMM+RS back to SP rows (resolves
-    matmul_rs). Padding lanes write to the scratch page."""
+    chunk (resolves ag_matmul), chunk K/V written into layer ``layer`` of
+    the stacked paged pools, chunk queries attending over that layer's
+    pages (prefix + the chunk itself, causal at absolute positions),
+    GEMM+RS back to SP rows (resolves matmul_rs). Padding lanes write to
+    the scratch page."""
     b, s_loc, d = x_sp.shape
     tp = pcfg.tp
     c = s_loc * tp
@@ -467,11 +479,11 @@ def attention_prefill_chunk(
 
         with obs.scope("kv_write"):
             rows, first, n = table_row[None], start[None], n_valid[None]
-            pool_k = _write_pages(pool_k, rows, first, n, k)
-            pool_v = _write_pages(pool_v, rows, first, n, v)
+            pool_k = _write_pages(pool_k, layer, rows, first, n, k)
+            pool_v = _write_pages(pool_v, layer, rows, first, n, v)
 
-        k_all = _gather_pages(pool_k, table_row[None, :], hd)
-        v_all = _gather_pages(pool_v, table_row[None, :], hd)
+        k_all = _gather_pages(pool_k, layer, table_row[None, :], hd)
+        v_all = _gather_pages(pool_v, layer, table_row[None, :], hd)
         with obs.scope("chunk_attend"):
             # all-masked rows would NaN; an idle shard (n_valid == 0) attends
             # one scratch position instead, and the caller discards its output
@@ -512,8 +524,9 @@ def attention_prefill_chunk_cp(
     info: TPInfo,
     p: dict,
     x_sp: Array,       # (1, C/(cp*tp), D) — this rank's placement rows, SP over tp
-    pool_k: Array,     # (num_pages, Hkv_loc, rows, width): page_rows
+    pool_k: Array,     # (L, num_pages, Hkv_loc, rows, width): page_rows
     pool_v: Array,
+    layer: Array,      # () int32: this layer's index into the pools
     table_row: Array,  # (P,) int32 — the request's block table
     start: Array,      # scalar int32: absolute position of the chunk's 1st token
     n_valid: Array,    # scalar int32: real tokens in the chunk (rest padding)
@@ -563,11 +576,11 @@ def attention_prefill_chunk_cp(
     k_ord = lax.all_gather(k[0], DATA_AXIS, axis=0, tiled=True)[inv_perm]
     v_ord = lax.all_gather(v[0], DATA_AXIS, axis=0, tiled=True)[inv_perm]
     rows, first, n = table_row[None], start[None], n_valid[None]
-    pool_k = _write_pages(pool_k, rows, first, n, k_ord[None])
-    pool_v = _write_pages(pool_v, rows, first, n, v_ord[None])
+    pool_k = _write_pages(pool_k, layer, rows, first, n, k_ord[None])
+    pool_v = _write_pages(pool_v, layer, rows, first, n, v_ord[None])
 
-    k_all = _gather_pages(pool_k, table_row[None, :], hd)
-    v_all = _gather_pages(pool_v, table_row[None, :], hd)
+    k_all = _gather_pages(pool_k, layer, table_row[None, :], hd)
+    v_all = _gather_pages(pool_v, layer, table_row[None, :], hd)
     limit = start + jnp.maximum(n_valid, 1)
     if cp_attend == "dense":
         o = _chunk_attend(q, k_all, v_all, pos_own, limit)

@@ -444,6 +444,16 @@ class LM:
         return {"attn": {"k": jax.ShapeDtypeStruct(shape, dtype),
                          "v": jax.ShapeDtypeStruct(shape, dtype)}}
 
+    def _scan_paged(self, body, h, params: dict, pools: dict):
+        """Run ``body((h, pool_k, pool_v), (layer_params, layer))`` over the
+        layers. The stacked pools ride in the carry: each layer writes and
+        reads its own pages in place at its index, and no layer's pool is
+        sliced out or stacked back. Returns (h, pool_k, pool_v)."""
+        xs = (params["layers"], jnp.arange(self.plan.n_super, dtype=jnp.int32))
+        (h, pk, pv), _ = lax.scan(
+            body, (h, pools["attn"]["k"], pools["attn"]["v"]), xs)
+        return h, pk, pv
+
     def decode_step_paged_local(
         self,
         params: dict,
@@ -469,21 +479,20 @@ class LM:
                     lengths, cfg.d_model)[:, None, :].astype(h.dtype)
 
         def body(carry, xs):
-            p_layer, pk, pv = xs
+            hh, pk, pv = carry
+            p_layer, layer = xs
             pl = self._unpack_layer(p_layer)
             hh, pk, pv = blocks.attention_decode_paged(
-                cfg, pcfg, info, pl["attn"], carry, pk, pv, table, lengths,
-                active)
+                cfg, pcfg, info, pl["attn"], hh, pk, pv, layer, table,
+                lengths, active)
             if cfg.family == "moe":
                 hh = blocks.moe_decode(cfg, pcfg, info, pl["ffn"], hh)
             else:
                 hh = blocks.mlp_decode(cfg, pcfg, info, pl["ffn"], hh)
-            return hh, (pk, pv)
+            return (hh, pk, pv), None
 
         with obs.scope("layers"):
-            h, (pk, pv) = lax.scan(
-                body, h,
-                (params["layers"], pools["attn"]["k"], pools["attn"]["v"]))
+            h, pk, pv = self._scan_paged(body, h, params, pools)
         with obs.scope("logits"):
             ln_f = fsdp_get(params["top"]["ln_f"], self.top_specs["ln_f"],
                             pcfg, h.dtype)
@@ -529,20 +538,20 @@ class LM:
                     pos, cfg.d_model)[None].astype(h.dtype)
 
         def body(carry, xs):
-            p_layer, pk, pv = xs
+            hh, pk, pv = carry
+            p_layer, layer = xs
             pl = self._unpack_layer(p_layer)
             hh, pk, pv = blocks.attention_prefill_chunk(
-                cfg, pcfg, info, pl["attn"], carry, pk, pv, row, start, n_valid)
+                cfg, pcfg, info, pl["attn"], hh, pk, pv, layer, row, start,
+                n_valid)
             if cfg.family == "moe":
                 hh = blocks.moe_train(cfg, pcfg, info, pl["ffn"], hh, None)
             else:
                 hh = blocks.mlp_train(cfg, pcfg, info, pl["ffn"], hh)
-            return hh, (pk, pv)
+            return (hh, pk, pv), None
 
         with obs.scope("layers"):
-            h, (pk, pv) = lax.scan(
-                self._remat(body), h,
-                (params["layers"], pools["attn"]["k"], pools["attn"]["v"]))
+            h, pk, pv = self._scan_paged(self._remat(body), h, params, pools)
         with obs.scope("logits"):
             ln_f = fsdp_get(params["top"]["ln_f"], self.top_specs["ln_f"],
                             pcfg, h.dtype)
@@ -617,21 +626,20 @@ class LM:
             h = h + sinusoidal_positions(pos_loc, cfg.d_model)[None].astype(h.dtype)
 
         def body(carry, xs):
-            p_layer, pk, pv = xs
+            hh, pk, pv = carry
+            p_layer, layer = xs
             pl = self._unpack_layer(p_layer)
             hh, pk, pv = blocks.attention_prefill_chunk_cp(
-                cfg, pcfg, info, pl["attn"], carry, pk, pv, row, start,
+                cfg, pcfg, info, pl["attn"], hh, pk, pv, layer, row, start,
                 n_valid, rows_own, inv_perm, placement=placement,
                 cp_attend=cp_attend)
             if cfg.family == "moe":
                 hh = blocks.moe_train(cfg, pcfg, info, pl["ffn"], hh, None)
             else:
                 hh = blocks.mlp_train(cfg, pcfg, info, pl["ffn"], hh)
-            return hh, (pk, pv)
+            return (hh, pk, pv), None
 
-        h, (pk, pv) = lax.scan(
-            self._remat(body), h,
-            (params["layers"], pools["attn"]["k"], pools["attn"]["v"]))
+        h, pk, pv = self._scan_paged(self._remat(body), h, params, pools)
         ln_f = fsdp_get(params["top"]["ln_f"], self.top_specs["ln_f"], pcfg, h.dtype)
         # last-valid-token logits: the row lives on exactly one (cp, tp)
         # rank under the placement map — one-hot select, then replicate

@@ -69,17 +69,19 @@ def test_flash_decode_compiles_at_serving_shapes(one_chip, s_len):
 @pytest.mark.parametrize("hkv,d", [(8, 64), (2, 64), (8, 128)],
                          ids=["tp1", "tp4", "hd128"])
 def test_paged_flash_decode_compiles_at_serving_shapes(one_chip, hkv, d):
-    """The benchmark's paged decode: 32 slots, 2800 pages of 16, 256 pages
-    a slot, 32q/8kv x 64 (a 64-wide head read lane-dense); at tp=4 each
-    rank holds 2 KV heads; and a 128-wide head."""
+    """The benchmark's paged decode: 32 slots, 2800 pages of 16 in each of
+    40 layers' pools, 256 pages a slot, 32q/8kv x 64 (a 64-wide head read
+    lane-dense); at tp=4 each rank holds 2 KV heads; and a 128-wide
+    head."""
     from repro.kernels import flash_decode as fd
 
     def s(shape, dt=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
-    pool = s((2800, hkv, *fd.page_rows(16, d)))
+    pool = s((40, 2800, hkv, *fd.page_rows(16, d)))
     _kernel_compiles(fd.paged_flash_decode, s((32, 4 * hkv, d)), pool, pool,
-                     s((32, 256), jnp.int32), s((32,), jnp.int32))
+                     s((32, 256), jnp.int32), s((32,), jnp.int32),
+                     s((), jnp.int32))
 
 
 @pytest.mark.parametrize("b,l", [(1, 2048), (4, 512)])
@@ -138,11 +140,53 @@ def _compiled_step(built, mesh) -> str:
     return built.fn.lower(*shapes).compile().as_text()
 
 
+_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*?) ([a-z][\w\-]*)\(")
+
+
+_CARRY = ("parameter", "get-tuple-element", "tuple", "while")
+
+
+def _assert_pools_stay_in_place(text, built):
+    """The stacked K/V pools ride the layer loop's carry. No instruction
+    yields one layer's pool; none copies, slices or writes back a pool
+    (a ``copy``, ``dynamic-slice`` or ``dynamic-update-slice``, alone or
+    as a fusion's root); under ``layers/`` the only pool-sized result,
+    besides the loop's carry, is the in-place K/V scatter; and the
+    donated pools alias the outputs."""
+    stacked = built.in_shapes[1]["attn"]["k"].shape  # (L, pages, ...)
+    layer_size = np.prod(stacked[1:])
+    entry, pool_params = False, set()
+    for line in text.splitlines():
+        entry = entry or line.startswith("ENTRY ")
+        m = _INSTR.match(line)
+        if not m:
+            continue
+        name, result, opcode = m.groups()
+        on = re.search(r'op_name="([^"]*)"', line)
+        for dims in re.findall(r"\w+\[([\d,]*)\]", result):
+            dims = tuple(int(x) for x in dims.split(",") if x)
+            if stacked[1] not in dims or np.prod(dims) < layer_size:
+                continue
+            assert dims == stacked, line[:200]
+            assert not re.search(r"copy|dynamic[-_](update[-_])?slice",
+                                 f"{name} {opcode}"), line[:200]
+            if on and "/layers/" in on.group(1) and opcode not in _CARRY:
+                assert on.group(1).endswith("/kv_write/scatter"), line[:200]
+            if entry and opcode == "parameter":
+                pool_params.add(re.search(r"parameter\((\d+)\)",
+                                          line).group(1))
+    aliased = set(re.findall(r"\{[\d,]*\}: \((\d+), \{\}, \w+-alias\)",
+                             text.splitlines()[0]))
+    assert len(pool_params) == 2 and pool_params <= aliased, (pool_params,
+                                                              aliased)
+
+
 def test_paged_decode_step_compiles_with_pallas(topo, monkeypatch):
     """One paged-decode step of granite-3-2b at full width and depth 2,
     bf16, with the kernels steered to the chip's path: the compiled step
-    holds the paged flash-decode kernel and no whole-table gather (no
-    instruction under the ``paged_gather`` scope)."""
+    holds the paged flash-decode kernel, with the result signature the
+    benchmark knows it by, and no whole-table gather (no instruction
+    under the ``paged_gather`` scope); the pools stay in place."""
     import dataclasses
 
     from repro.configs import get_config
@@ -167,6 +211,14 @@ def test_paged_decode_step_compiles_with_pallas(topo, monkeypatch):
     names = re.findall(r'op_name="([^"]*)"', text)
     assert any("/attn/flash_decode/" in n for n in names)
     assert not any("/paged_gather/" in n for n in names)
+    assert _kernels(text, cfg).get("flash_decode")
+    _assert_pools_stay_in_place(text, built)
+
+
+def _kernels(text, cfg):
+    from bench.kernels import Program
+
+    return Program(text, {"head_dim": cfg.head_dim}).kernels
 
 
 @pytest.mark.parametrize("kind", ["decode", "prefill"])
@@ -174,7 +226,8 @@ def test_moe_paged_programs_compile_with_pallas(topo, monkeypatch, kind):
     """granite-moe-3b-a800m at full width and depth 2, bf16, Pallas on:
     the benchmark's paged decode (32 slots) and 256-token chunked
     prefill compile for a v5e, each holding the experts' grouped-GEMM
-    kernels under the ``moe/experts`` scope."""
+    kernels under the ``moe/experts`` scope, the decode the paged
+    flash-decode kernel too; the pools stay in place."""
     import dataclasses
 
     from repro.configs import get_config
@@ -205,3 +258,5 @@ def test_moe_paged_programs_compile_with_pallas(topo, monkeypatch, kind):
                if "tpu_custom_call" in line and "/moe/" in line]
     assert sum(bool(re.search(r"/moe/(checkpoint/)?experts/", line))
                for line in kernels) == 2
+    assert bool(_kernels(text, cfg).get("flash_decode")) == (kind == "decode")
+    _assert_pools_stay_in_place(text, built)

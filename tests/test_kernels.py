@@ -183,9 +183,37 @@ def test_paged_flash_decode_matches_gathered_reference(hq, hkv, d, dtype,
     slot's last block prefetches the next slot's first."""
     q, table, lens, (ck, cv), (dk, dv) = _paged_case(hq, hkv, d, dtype,
                                                      garbage)
-    og, lg = ops.paged_flash_decode(q, dk, dv, table, lens,
+    og, lg = ops.paged_flash_decode(q, dk[None], dv[None], table, lens, 0,
                                     bkv=2 * PAGE, force="pallas")
-    ow, lw = ops.paged_flash_decode(q, ck, cv, table, lens, force="ref")
+    ow, lw = ops.paged_flash_decode(q, ck[None], cv[None], table, lens, 0,
+                                    force="ref")
+    np.testing.assert_allclose(np.asarray(og), np.asarray(ow), atol=2e-5, rtol=2e-4)
+    np.testing.assert_allclose(np.asarray(lg), np.asarray(lw), atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2])
+@pytest.mark.parametrize("hq,hkv,d,dtype", [
+    (32, 8, 64, jnp.bfloat16), (8, 2, 128, jnp.float32)],
+    ids=["32-8x64-bf16", "8-2x128"])
+def test_paged_flash_decode_reads_one_layer_of_stacked_pools(hq, hkv, d,
+                                                             dtype, layer):
+    """Pools stacked over three layers, each layer's pages different, read
+    at ``layer``: the kernel's output is the gathered reference of that
+    layer's pool alone. Table entries past a slot's live pages point at
+    random pages, which hold other slots' tokens; neither they nor the
+    other layers reach o or lse."""
+    q, table, lens, clean, dirty = _paged_case(hq, hkv, d, dtype, np.nan)
+    scales = (1.0, -0.5, 2.0)  # exact in bf16: three different layers
+    live = -(-np.asarray(lens) // PAGE)
+    anywhere = R.randint(0, clean[0].shape[0], table.shape)
+    table = jnp.asarray(np.where(np.arange(table.shape[1]) < live[:, None],
+                                 table, anywhere), jnp.int32)
+    stacked = [jnp.stack([pool * s for s in scales]) for pool in dirty]
+    og, lg = ops.paged_flash_decode(q, *stacked, table, lens,
+                                    jnp.int32(layer), bkv=2 * PAGE,
+                                    force="pallas")
+    ck, cv = (pool[None] * scales[layer] for pool in clean)
+    ow, lw = ops.paged_flash_decode(q, ck, cv, table, lens, 0, force="ref")
     np.testing.assert_allclose(np.asarray(og), np.asarray(ow), atol=2e-5, rtol=2e-4)
     np.testing.assert_allclose(np.asarray(lg), np.asarray(lw), atol=2e-5, rtol=2e-5)
 

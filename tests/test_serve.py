@@ -2,6 +2,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import ARCHS, reduced
@@ -354,6 +355,51 @@ def test_paged_decode_step_kernel_matches_reference_path(one_device_mesh,
         np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2])
+@pytest.mark.parametrize("kind", ["decode", "prefill"])
+def test_paged_write_changes_only_the_layers_touched_rows(kind, layer):
+    """The K/V write into pools stacked over three layers, pages of 16
+    tokens stored two to a 128-lane row: a decode write (one token a
+    slot, an idle lane among them) and a 256-token prefill chunk that
+    starts mid-page, carries 250 tokens and straddles 16 pages. Exactly
+    the written tokens' lanes of layer ``layer`` change; every other
+    layer, page and row, scratch page 0 included, stays bit for bit. The
+    idle lane's table row lists a live lane's pages, as a freed slot's
+    stale row may: it must write nowhere but scratch page 0, or its
+    unchanged copy of the page could land over the live lane's token."""
+    from repro.kernels.flash_decode import page_rows
+    from repro.models.blocks import _write_pages
+
+    rs = np.random.RandomState(layer)
+    n_layers, n_pages, h, hd, ps = 3, 48, 2, 64, 16
+    rows, width = page_rows(ps, hd)
+    parts = width // hd
+    pool = rs.randn(n_layers, n_pages, h, rows, width).astype(np.float32)
+    if kind == "decode":  # lane 2 idle
+        start, n_valid, c = np.array([0, 31, 17, 40]), np.array([1, 1, 0, 1]), 1
+        per_stream = 4
+    else:
+        start, n_valid, c = np.array([37]), np.array([250]), 256
+        per_stream = 20
+    ids = 1 + rs.permutation(n_pages - 1)[:len(start) * per_stream]
+    table = ids.reshape(len(start), per_stream).astype(np.int32)
+    if kind == "decode":  # the idle lane's row still lists lane 1's pages
+        table[2] = table[1]
+    x = rs.randn(len(start), c, h, hd).astype(np.float32)
+
+    want = pool.copy()
+    for s in range(len(start)):
+        for t in range(n_valid[s]):
+            pos = start[s] + t
+            r, p = divmod(pos % ps, parts)
+            want[layer, table[s, pos // ps], :, r,
+                 p * hd:(p + 1) * hd] = x[s, t]
+    got = _write_pages(jnp.asarray(pool), jnp.int32(layer),
+                       jnp.asarray(table), jnp.asarray(start, jnp.int32),
+                       jnp.asarray(n_valid, jnp.int32), jnp.asarray(x))
+    np.testing.assert_array_equal(np.asarray(got), want)
 
 
 # ---------------------------------------------------------------------------
