@@ -1,4 +1,4 @@
-"""Serve a small LM with batched requests through the decode engine.
+"""Serve a small LM with batched requests through the paged engine.
 
   XLA_FLAGS=--xla_force_host_platform_device_count=4 \
   PYTHONPATH=src python examples/serve_lm.py
@@ -21,9 +21,8 @@ def main():
         batch=4, max_len=64, requests=8, new_tokens=8, temperature=0.7,
         dtype="float32", no_fsdp=False)
     eng = serve_mod.run(ns)
-    print(f"\nKV cache fill after run: {eng.cache_len}/{ns.max_len}")
     m = eng.metrics()
-    print(f"TTFT {m.ttft_mean_s * 1e3:.1f}ms mean / "
+    print(f"\nTTFT {m.ttft_mean_s * 1e3:.1f}ms mean / "
           f"{m.ttft_max_s * 1e3:.1f}ms max; "
           f"TPOT {m.tpot_mean_s * 1e3:.2f}ms; "
           f"queue depth {m.queue_depth_mean:.2f} mean "

@@ -7,8 +7,8 @@ of ``core.overlap``), the kernel lowering (the shmem tile executor) and
 the dual-op backward are all derived. This module keeps:
 
   - thin functional wrappers with the historical signatures (callers
-    inside ``shard_map`` and the benchmarks use these; they delegate to
-    the declared ops with no deprecation cost),
+    inside ``shard_map`` use these; they delegate to the declared ops
+    with no deprecation cost),
   - the hierarchical (Fig. 10) 2-level variants, which compose two mesh
     axes and therefore sit outside the single-axis declaration shape,
   - the stand-alone chunked collectives used by grad sync & decode.

@@ -6,10 +6,8 @@ Builds BOTH serving programs from one model:
     stream per data shard;
   * the paged decode step (``build_paged_decode_step``) — one token per
     slot at per-slot positions through the block tables;
-and drives them with :class:`repro.serve.PagedEngine` under a seeded
-synthetic load stream (``repro.serve.load``). ``--tokenwise`` instead
-runs the legacy dense-cache engine (prompt ingestion token-by-token
-through the decode program) for comparison.
+and drives them with :class:`repro.serve.PagedEngine`, the only serving
+engine, under a seeded synthetic load stream (``repro.serve.load``).
 
 Prefill and decode may carry SEPARATE overlap policies: prefill is
 throughput-bound (ag_matmul/matmul_rs in the chunk projections), decode
@@ -35,7 +33,6 @@ from ..configs import ARCHS, get_config, reduced
 from ..configs.base import ParallelConfig, ShapeConfig
 from ..ops.policy import OverlapPolicy
 from ..serve import (
-    Engine,
     LoadSpec,
     PagedEngine,
     PagedKVCache,
@@ -46,7 +43,6 @@ from ..serve import (
 from .cache import enable_compile_cache
 from .mesh import make_mesh
 from .steps import (
-    build_decode_step,
     build_paged_decode_step,
     build_prefill_chunk_cp_step,
     build_prefill_chunk_step,
@@ -132,24 +128,6 @@ def build_paged_engine(
                        prefill_cp=prefill_cp, cp_placement=cp_placement)
 
 
-def build_tokenwise_engine(
-    cfg, pcfg: ParallelConfig, batch: int, max_len: int, mesh, *,
-    cache_dtype=None, seed: int = 0, eos_id: int = -1,
-) -> Engine:
-    """The legacy path: dense per-slot KV caches, prompt ingestion
-    token-by-token through the decode program."""
-    cache_dtype = cache_dtype or jnp.dtype(pcfg.compute_dtype)
-    shape = ShapeConfig("serve", seq_len=max_len, global_batch=batch,
-                        kind="decode")
-    built = build_decode_step(cfg, pcfg, shape, mesh, cache_dtype=cache_dtype)
-    params, _ = built.model.init(jax.random.PRNGKey(seed),
-                                 jnp.dtype(pcfg.param_dtype))
-    caches = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
-                          built.in_shapes[1])
-    return Engine(built.fn, params, caches, batch=batch, max_len=max_len,
-                  eos_id=eos_id, seed=seed, pcfg=pcfg)
-
-
 def run(args):
     trace_path = getattr(args, "trace", None)
     if trace_path:
@@ -166,26 +144,21 @@ def run(args):
         overlap=OverlapPolicy(mode=getattr(args, "overlap", "none")),
     )
     mesh = make_mesh(args.dp, args.tp)
-    tokenwise = getattr(args, "tokenwise", False)
-    if tokenwise:
-        eng = build_tokenwise_engine(cfg, pcfg, args.batch, args.max_len, mesh)
-    else:
-        prefill_policy = None
-        if getattr(args, "prefill_overlap", None):
-            prefill_policy = OverlapPolicy(mode=args.prefill_overlap)
-        scfg = ServeConfig(
-            batch=args.batch, max_len=args.max_len,
-            page_size=getattr(args, "page_size", 16),
-            num_pages=getattr(args, "num_pages", 0),
-            chunk=getattr(args, "chunk", 16),
-            token_budget=getattr(args, "token_budget", 64),
-        )
-        eng = build_paged_engine(
-            cfg, pcfg, scfg, mesh, prefill_policy=prefill_policy,
-            prefill_cp=getattr(args, "prefill_cp", False),
-            cp_placement=getattr(args, "cp_placement", "zigzag"),
-            cp_attend=getattr(args, "cp_attend", "ring"))
-    print("engine:", "tokenwise" if tokenwise else "paged")
+    prefill_policy = None
+    if getattr(args, "prefill_overlap", None):
+        prefill_policy = OverlapPolicy(mode=args.prefill_overlap)
+    scfg = ServeConfig(
+        batch=args.batch, max_len=args.max_len,
+        page_size=getattr(args, "page_size", 16),
+        num_pages=getattr(args, "num_pages", 0),
+        chunk=getattr(args, "chunk", 16),
+        token_budget=getattr(args, "token_budget", 64),
+    )
+    eng = build_paged_engine(
+        cfg, pcfg, scfg, mesh, prefill_policy=prefill_policy,
+        prefill_cp=getattr(args, "prefill_cp", False),
+        cp_placement=getattr(args, "cp_placement", "zigzag"),
+        cp_attend=getattr(args, "cp_attend", "ring"))
     print("overlap modes:", eng.overlap_modes())
     spec = LoadSpec(
         n_requests=args.requests,
@@ -231,8 +204,6 @@ def main():
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--dtype", default="float32")
     ap.add_argument("--no-fsdp", action="store_true")
-    ap.add_argument("--tokenwise", action="store_true",
-                    help="legacy dense-cache engine (token-by-token prefill)")
     ap.add_argument("--overlap", default="none",
                     help="decode-phase overlap mode")
     ap.add_argument("--prefill-overlap", default=None,

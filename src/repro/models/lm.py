@@ -327,54 +327,6 @@ class LM:
                          h.dtype).T
         return vocab_parallel_logits(h_last, w_out, info, cfg.vocab_size)
 
-    def prefill_with_cache_local(
-        self,
-        params: dict,
-        tokens: Array,  # (B_loc, S) int32
-        s_max: int,  # KV cache capacity (>= S)
-        extra: Optional[dict] = None,
-    ) -> Tuple[Array, dict]:
-        """Batched chunked-prefill: one forward pass that BOTH computes the
-        last-token logits and materializes the decode KV caches — the
-        serving fast path (vs. token-by-token prompt ingestion). Dense/MoE
-        families, heads-sharded KV."""
-        cfg, pcfg, info = self.cfg, self.pcfg, self.info
-        assert cfg.family in ("dense", "moe"), cfg.family
-        assert not self._kv_seq_sharded(), "prefill cache path is heads-sharded"
-        b, s = tokens.shape
-        tp = pcfg.tp
-        s_loc = s // tp
-        me = lax.axis_index(MODEL_AXIS)
-        cdt = jnp.dtype(pcfg.compute_dtype)
-        embed = fsdp_get(params["top"]["embed"], self.top_specs["embed"], pcfg, cdt)
-        h = embed_lookup_sp(tokens, embed, info, tp)
-
-        def body(carry, xs):
-            pl = self._unpack_layer(xs)
-            hh, (k, v) = blocks.attention_train(
-                cfg, pcfg, info, pl["attn"], carry, return_kv=True
-            )
-            if cfg.family == "moe":
-                hh = blocks.moe_train(cfg, pcfg, info, pl["ffn"], hh, None)
-            else:
-                hh = blocks.mlp_train(cfg, pcfg, info, pl["ffn"], hh)
-            pad = s_max - k.shape[2]
-            kc = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0)))
-            vc = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0)))
-            return hh, {"attn": {"k": kc, "v": vc}}
-
-        h, caches = lax.scan(self._remat(body), h, params["layers"])
-        ln_f = fsdp_get(params["top"]["ln_f"], self.top_specs["ln_f"], pcfg, h.dtype)
-        # replicate the last rank's final row over TP before the
-        # vocab-parallel projection (see prefill_logits_local)
-        keep = (me == tp - 1).astype(h.dtype)
-        h_last = lax.psum(h[:, -1, :] * keep, MODEL_AXIS)
-        h_last = rmsnorm(h_last, ln_f, cfg.norm_eps)
-        un_name = "embed" if cfg.tie_embeddings else "unembed"
-        w_out = fsdp_get(params["top"][un_name], self.top_specs[un_name], pcfg,
-                         h.dtype).T
-        return vocab_parallel_logits(h_last, w_out, info, cfg.vocab_size), caches
-
     # ------------------------------------------------------------------
     # Serving
     # ------------------------------------------------------------------

@@ -1,26 +1,17 @@
-"""Serving engines over the shard_map'd SPMD programs.
+"""The serving engine over the shard_map'd SPMD programs.
 
-Two engines share one protocol (``add / can_accept / step / run /
-metrics``, see :func:`repro.serve.load.drive`):
+One engine, :class:`PagedEngine`, over a block/paged KV cache
+(serve/kvcache.py), a dedicated chunked-prefill program that writes
+straight into the page pool, and continuous batching with mixed
+prefill+decode scheduling under a token budget (serve/scheduler.py).
+Prefill and decode are separate compiled programs and may carry
+separate overlap policies (prefill resolves ag_matmul/matmul_rs in the
+chunk projections; decode resolves flash_decode/a2a_ep). Drivers step
+it through ``add / can_accept / step / leftover`` (see
+:func:`repro.serve.load.drive`).
 
-* :class:`Engine` — the original slot loop: prompt tokens are fed
-  one-by-one through the decode program against dense per-slot KV
-  caches. Slots advance on INDEPENDENT per-slot lengths (a freed slot's
-  successor starts at position 0, so stale KV is masked out exactly —
-  no slot-reuse leak), and a slot that hits the cache capacity is
-  finished with an explicit ``truncated`` flag instead of silently
-  stranding the run.
-
-* :class:`PagedEngine` — the production path: a block/paged KV cache
-  (serve/kvcache.py), a dedicated chunked-prefill program that writes
-  straight into the page pool, and continuous batching with mixed
-  prefill+decode scheduling under a token budget (serve/scheduler.py).
-  Prefill and decode are separate compiled programs and may carry
-  separate overlap policies (prefill resolves ag_matmul/matmul_rs in
-  the chunk projections; decode resolves flash_decode/a2a_ep).
-
-Serving metrics: both engines keep the standard latency/occupancy
-counters as they run — TTFT (arrival -> first generated token), TPOT
+Serving metrics: the engine keeps the standard latency/occupancy
+counters as it runs — TTFT (arrival -> first generated token), TPOT
 (mean seconds per output token after the first), queue depth and slot
 occupancy sampled per step, prefill-vs-decode step split — reduced into
 a :class:`Metrics` snapshot via ``metrics()``.
@@ -36,7 +27,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Callable, List, Optional
+from typing import Callable, List
 
 import jax.numpy as jnp
 import numpy as np
@@ -55,7 +46,7 @@ class Request:
     done: bool = False
     truncated: bool = False  # clipped by cache capacity, not eos/max_new
     # serving-metrics timestamps (time.perf_counter seconds)
-    t_arrive: float = 0.0   # stamped by Engine.add
+    t_arrive: float = 0.0   # stamped by PagedEngine.add
     t_first: float = 0.0    # first generated (non-prompt) token
     t_done: float = 0.0     # request completion
 
@@ -108,213 +99,7 @@ def _describe(policy, op: str) -> str:
     return desc + f"/{r.wire}"
 
 
-class _EngineBase:
-    """Shared bookkeeping: metrics accumulators + the run loop."""
-
-    def _init_metrics(self, seed: int):
-        self.rng = np.random.RandomState(seed)
-        self._steps = 0
-        self._steps_prefill = 0
-        self._steps_decode = 0
-        self._completed = 0
-        self._truncated = 0
-        self._tokens_completed = 0
-        self._ttfts: List[float] = []
-        self._tpots: List[float] = []
-        # per-step samples of queue depth and slot occupancy, kept as
-        # running sums (and the deepest queue) for the server's lifetime
-        self._queue_sum = 0
-        self._queue_max = 0
-        self._occ_sum = 0.0
-
-    def _sample_load(self, queue: int, occupancy: float) -> None:
-        self._queue_sum += queue
-        self._queue_max = max(self._queue_max, queue)
-        self._occ_sum += occupancy
-
-    def _finish(self, req: Request, now: float) -> None:
-        req.done = True
-        req.t_done = now
-        self._completed += 1
-        self._truncated += bool(req.truncated)
-        self._tokens_completed += len(req.out_tokens)
-        if req.t_first:
-            self._ttfts.append(req.t_first - req.t_arrive)
-            if len(req.out_tokens) > 1:
-                self._tpots.append((req.t_done - req.t_first)
-                                   / (len(req.out_tokens) - 1))
-
-    def _live_requests(self) -> List[Request]:
-        raise NotImplementedError
-
-    def metrics(self) -> Metrics:
-        """Snapshot of the run's serving metrics."""
-        n_steps = max(1, self._steps)
-        tokens = self._tokens_completed
-        tokens += sum(len(r.out_tokens) for r in self._live_requests())
-        return Metrics(
-            requests_completed=self._completed,
-            tokens_generated=tokens,
-            steps=self._steps,
-            ttft_mean_s=(sum(self._ttfts) / len(self._ttfts)
-                         if self._ttfts else 0.0),
-            ttft_max_s=max(self._ttfts, default=0.0),
-            tpot_mean_s=(sum(self._tpots) / len(self._tpots)
-                         if self._tpots else 0.0),
-            queue_depth_mean=self._queue_sum / n_steps,
-            queue_depth_max=self._queue_max,
-            slot_occupancy_mean=self._occ_sum / n_steps,
-            steps_prefill=self._steps_prefill,
-            steps_decode=self._steps_decode,
-            requests_truncated=self._truncated,
-        )
-
-    def step(self) -> bool:
-        raise NotImplementedError
-
-    def leftover(self) -> List[Request]:
-        return self._live_requests()
-
-    def run(self, max_steps: int = 256):
-        """Drive all requests to completion (or max_steps); returns the
-        requests still live/pending when the step budget runs out."""
-        for _ in range(max_steps):
-            if not self.step():
-                break
-        return self.leftover()
-
-
-class Engine(_EngineBase):
-    """step_fn(params, caches, cache_len, token) -> (logits, new_caches)
-    — the jit(shard_map(decode_step_local)) closure built by the
-    launcher. ``cache_len`` is passed as per-slot (B,) lengths."""
-
-    # decode-path ops whose effective overlap mode the engine reports
-    OVERLAP_OPS = ("ag_matmul", "matmul_rs", "a2a_ep", "flash_decode")
-
-    def __init__(
-        self,
-        step_fn: Callable,
-        params,
-        init_caches,
-        batch: int,
-        max_len: int,
-        eos_id: int = -1,
-        seed: int = 0,
-        pcfg=None,  # ParallelConfig: per-op overlap-mode provenance
-    ):
-        self.step_fn = step_fn
-        self.params = params
-        self.caches = init_caches
-        self.batch = batch
-        self.max_len = max_len
-        self.eos_id = eos_id
-        self.pcfg = pcfg
-        self.requests: List[Optional[Request]] = [None] * batch
-        self.pending: List[Request] = []
-        self.slot_lens = np.zeros((batch,), np.int32)
-        self._prompt_cursor = [0] * batch
-        self._last = np.zeros((batch,), np.int32)
-        self._init_metrics(seed)
-
-    @property
-    def cache_len(self) -> int:
-        """Deepest slot position (display/compat; slots advance per-slot)."""
-        return int(self.slot_lens.max())
-
-    def overlap_modes(self) -> dict:
-        """Effective per-op overlap lowering of the compiled decode step
-        ('mode/backend[/xN]/wire', resolved through the policy + engine
-        registry); {} when no pcfg given."""
-        if self.pcfg is None:
-            return {}
-        return {op: _describe(self.pcfg.policy, op) for op in self.OVERLAP_OPS}
-
-    def _live_requests(self) -> List[Request]:
-        return list(self.pending) + [r for r in self.requests if r]
-
-    # ------------------------------------------------------------------
-    def add(self, req: Request) -> bool:
-        req.t_arrive = time.perf_counter()
-        self.pending.append(req)
-        return True
-
-    def can_accept(self) -> bool:
-        return True  # unbounded pending list (PagedEngine bounds its queue)
-
-    def _admit(self):
-        for i in range(self.batch):
-            if self.requests[i] is None and self.pending:
-                self.requests[i] = self.pending.pop(0)
-                self._prompt_cursor[i] = 0
-                self.slot_lens[i] = 0  # fresh slot: stale KV is masked out
-
-    def _next_tokens(self, last_sampled: np.ndarray) -> np.ndarray:
-        toks = np.zeros((self.batch, 1), np.int32)
-        for i, req in enumerate(self.requests):
-            if req is None:
-                continue
-            cur = self._prompt_cursor[i]
-            if cur < len(req.prompt):
-                toks[i, 0] = req.prompt[cur]
-                self._prompt_cursor[i] = cur + 1
-            else:
-                toks[i, 0] = last_sampled[i]
-        return toks
-
-    def _sample(self, logits: np.ndarray) -> np.ndarray:
-        out = np.zeros((self.batch,), np.int32)
-        for i, req in enumerate(self.requests):
-            if req is not None:
-                out[i] = _sample_row(self.rng, logits[i], req.temperature)
-        return out
-
-    # ------------------------------------------------------------------
-    def step(self) -> bool:
-        """One decode step over all occupied slots; False when idle."""
-        self._admit()
-        if all(r is None for r in self.requests) and not self.pending:
-            return False
-        self._sample_load(len(self.pending),
-                          sum(r is not None for r in self.requests) / self.batch)
-        toks = self._next_tokens(self._last)
-        logits, self.caches = self.step_fn(
-            self.params, self.caches, jnp.asarray(self.slot_lens),
-            jnp.asarray(toks),
-        )
-        self._steps += 1
-        self._steps_decode += 1
-        logits = np.asarray(logits)
-        now = time.perf_counter()
-        self._last = self._sample(logits)
-        for i, req in enumerate(self.requests):
-            if req is None:
-                continue
-            self.slot_lens[i] += 1
-            if self._prompt_cursor[i] >= len(req.prompt):
-                if not req.out_tokens:
-                    req.t_first = now
-                req.out_tokens.append(int(self._last[i]))
-                if (
-                    len(req.out_tokens) >= req.max_new_tokens
-                    or self._last[i] == self.eos_id
-                ):
-                    self._finish(req, now)
-                    self.requests[i] = None
-                    continue
-            if self.slot_lens[i] >= self.max_len:
-                # cache full mid-request: account for it explicitly
-                # instead of silently stranding the slot
-                req.truncated = True
-                self._finish(req, now)
-                self.requests[i] = None
-        return True
-
-    def run(self, max_steps: int = 256):
-        return super().run(max_steps)
-
-
-class PagedEngine(_EngineBase):
+class PagedEngine:
     """Continuous-batching engine over the paged KV pools.
 
     prefill_fn(params, pools, table_rows, starts, n_valids, tokens)
@@ -365,12 +150,20 @@ class PagedEngine(_EngineBase):
             batch=scfg.batch, max_len=scfg.max_len, page_size=scfg.page_size,
             num_pages=scfg.num_pages, dp_shards=dp_shards)
         self.sched = Scheduler(scfg, self.kv, dp_shards)
-        self._init_metrics(seed)
-
-    @property
-    def cache_len(self) -> int:
-        """Deepest slot fill (display/compat with the dense engine)."""
-        return int(self.kv.lens.max())
+        self.rng = np.random.RandomState(seed)
+        self._steps = 0
+        self._steps_prefill = 0
+        self._steps_decode = 0
+        self._completed = 0
+        self._truncated = 0
+        self._tokens_completed = 0
+        self._ttfts: List[float] = []
+        self._tpots: List[float] = []
+        # per-step samples of queue depth and slot occupancy, kept as
+        # running sums (and the deepest queue) for the server's lifetime
+        self._queue_sum = 0
+        self._queue_max = 0
+        self._occ_sum = 0.0
 
     def overlap_modes(self) -> dict:
         """Per-PHASE overlap provenance: 'phase:op' ->
@@ -394,7 +187,8 @@ class PagedEngine(_EngineBase):
                 out[f"{phase}:{op}"] = row
         return out
 
-    def _live_requests(self) -> List[Request]:
+    def leftover(self) -> List[Request]:
+        """Requests still queued or in a slot."""
         live = [s.req for s in self.sched.slots if s.req is not None]
         return list(self.sched.queue) + live
 
@@ -409,6 +203,23 @@ class PagedEngine(_EngineBase):
         return self.sched.queue_depth() < self.scfg.queue_cap
 
     # ------------------------------------------------------------------
+    def _sample_load(self, queue: int, occupancy: float) -> None:
+        self._queue_sum += queue
+        self._queue_max = max(self._queue_max, queue)
+        self._occ_sum += occupancy
+
+    def _finish(self, req: Request, now: float) -> None:
+        req.done = True
+        req.t_done = now
+        self._completed += 1
+        self._truncated += bool(req.truncated)
+        self._tokens_completed += len(req.out_tokens)
+        if req.t_first:
+            self._ttfts.append(req.t_first - req.t_arrive)
+            if len(req.out_tokens) > 1:
+                self._tpots.append((req.t_done - req.t_first)
+                                   / (len(req.out_tokens) - 1))
+
     def _emit(self, slot_id: int, tok: int, now: float) -> None:
         """Record one generated token for the slot's request; finish +
         release the slot on eos / max_new / capacity."""
@@ -511,5 +322,32 @@ class PagedEngine(_EngineBase):
             self._steps += 1
         return True
 
+    def metrics(self) -> Metrics:
+        """Snapshot of the run's serving metrics."""
+        n_steps = max(1, self._steps)
+        tokens = self._tokens_completed
+        tokens += sum(len(r.out_tokens) for r in self.leftover())
+        return Metrics(
+            requests_completed=self._completed,
+            tokens_generated=tokens,
+            steps=self._steps,
+            ttft_mean_s=(sum(self._ttfts) / len(self._ttfts)
+                         if self._ttfts else 0.0),
+            ttft_max_s=max(self._ttfts, default=0.0),
+            tpot_mean_s=(sum(self._tpots) / len(self._tpots)
+                         if self._tpots else 0.0),
+            queue_depth_mean=self._queue_sum / n_steps,
+            queue_depth_max=self._queue_max,
+            slot_occupancy_mean=self._occ_sum / n_steps,
+            steps_prefill=self._steps_prefill,
+            steps_decode=self._steps_decode,
+            requests_truncated=self._truncated,
+        )
+
     def run(self, max_steps: int = 10_000):
-        return super().run(max_steps)
+        """Drive all requests to completion (or max_steps); returns the
+        requests still live/pending when the step budget runs out."""
+        for _ in range(max_steps):
+            if not self.step():
+                break
+        return self.leftover()

@@ -1,13 +1,12 @@
 """Seeded synthetic request streams + the arrival-aware drive loop.
 
-The generator is the serving benchmark's workload: Poisson arrivals at
-``rate_rps`` with prompt lengths uniform over ``prompt_lens`` (the
-mixed 128–2048-token regime of bench_serve) and seeded token ids, so
-every run of a given (spec, vocab) pair replays the identical stream.
-:func:`drive` releases requests by wall clock and steps any engine
-implementing the shared protocol (``add / can_accept / step /
-leftover`` — both serve.Engine and serve.PagedEngine), applying
-backpressure when the engine's queue is full.
+The generator feeds the serving CLI and the chip smoke run: Poisson
+arrivals at ``rate_rps`` with prompt lengths uniform over
+``prompt_lens`` and seeded token ids, so every run of a given
+(spec, vocab) pair replays the identical stream. :func:`drive` releases
+requests by wall clock and steps a :class:`repro.serve.PagedEngine`
+(``add / can_accept / step / leftover``), applying backpressure when
+the engine's queue is full.
 """
 from __future__ import annotations
 

@@ -99,6 +99,65 @@ def test_ring_ag_trace_schema_and_bit_identity():
     assert "RING_TRACE_OK" in out
 
 
+_FUSED_BARRIER_SCRIPT = r"""
+import functools
+import jax, jax.numpy as jnp
+import numpy as np
+from jax.sharding import PartitionSpec as P
+from repro import obs, ops
+from repro.core.collective_matmul import make_sharded
+
+W = jax.device_count()
+assert W == 4, W
+mesh = jax.make_mesh((W,), ("tp",))
+specs = ((P(None, "tp"), P("tp", None), P(None, "tp"), P("tp", None)),
+         P(None, "tp"))
+rng = np.random.RandomState(0)
+m, k, n, f = 16 * W, 8 * W, 8 * W, 8 * W
+y, wo, wi, xr = (jnp.asarray(rng.randn(*s), jnp.float32)
+                 for s in ((m, k), (k, n), (n, f), (m, n)))
+
+def mid(r, x):  # the rank-local seam between the two halves
+    return jnp.tanh(r + x)
+
+def unfused(y, wo, wi, xr):
+    r = ops.matmul_rs(y, wo, axis="tp", mode="ring", backend="kernel",
+                      out_dtype=jnp.float32)
+    return ops.ag_matmul(mid(r, xr), wi, axis="tp", mode="ring",
+                         backend="kernel", out_dtype=jnp.float32)
+
+fused = functools.partial(ops.matmul_rs_ag_matmul, axis="tp", mode="ring",
+                          backend="kernel", out_dtype=jnp.float32, mid=mid)
+
+obs.enable()  # before the first compile: executor spans are trace-gated
+
+def barriers(fn):
+    fn = make_sharded(fn, mesh, *specs)
+    out = jax.block_until_ready(fn(y, wo, wi, xr))  # compile
+    obs.clear()
+    jax.block_until_ready(fn(y, wo, wi, xr))
+    ev = obs.events(clear=True)
+    assert ev, "no trace events: kernel backend not engaged?"
+    return np.asarray(out), sum(e.kind == "barrier" for e in ev)
+
+out_u, nb_u = barriers(unfused)
+out_f, nb_f = barriers(fused)
+np.testing.assert_allclose(out_f, out_u, rtol=1e-4, atol=1e-4)
+# the chain drops the pair's rs-exit and ag-entry rendezvous: two
+# barriers on every PE
+assert nb_f == nb_u - 2 * W, (nb_u, nb_f, W)
+print("FUSED_BARRIERS_OK", nb_u, nb_f)
+"""
+
+
+def test_fused_boundary_drops_two_barriers_per_pe():
+    """The chained ``push_rs_ring_ag`` protocol records exactly 2*W fewer
+    ``barrier`` events than the back-to-back ``matmul_rs`` ->
+    ``ag_matmul`` kernel pair, on the same values."""
+    out = run_devices(_FUSED_BARRIER_SCRIPT, devices=4)
+    assert "FUSED_BARRIERS_OK" in out
+
+
 # ---------------------------------------------------------------------------
 # Metrics pinned on a hand-built synthetic timeline (no devices needed)
 # ---------------------------------------------------------------------------

@@ -8,87 +8,47 @@ from jax.sharding import PartitionSpec as P
 from repro.configs import ARCHS, reduced
 from repro.configs.base import ParallelConfig
 from repro.models import build_model
-from repro.serve.engine import Engine, Request
+from repro.serve import PagedEngine, Request, ServeConfig
 
 PCFG = ParallelConfig(dp=1, tp=1, fsdp=False, compute_dtype="float32",
                       param_dtype="float32", overlap_mode="none")
+SCFG = ServeConfig(batch=2, max_len=32, page_size=8, chunk=4, token_budget=8)
 
 
-def _build(one_device_mesh, batch=2, s_max=32):
-    cfg = reduced(ARCHS["granite-3-2b"])
-    model = build_model(cfg, PCFG)
-    params, pspecs = model.init(jax.random.PRNGKey(0), jnp.float32)
-    caches = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
-                          model.cache_shapes(batch, s_max, jnp.float32))
-    cache_specs = jax.tree.map(lambda x: P(*([None] * x.ndim)), caches)
-    step = jax.jit(jax.shard_map(
-        lambda p, c, n, t: model.decode_step_local(p, c, n, t),
-        mesh=one_device_mesh,
-        in_specs=(pspecs, cache_specs, None, P(None, None)),
-        out_specs=(P(None, None), cache_specs), check_vma=False))
-    return cfg, params, caches, step
+@pytest.fixture(scope="module")
+def paged(one_device_mesh):
+    """A factory of fresh paged engines (empty pools, slots and metrics)
+    over one build of the two programs, for reduced granite-3-2b."""
+    from repro.launch.serve import build_paged_engine
+
+    built = build_paged_engine(reduced(ARCHS["granite-3-2b"]), PCFG, SCFG,
+                               one_device_mesh)
+
+    def fresh():
+        return PagedEngine(built.prefill_fn, built.decode_fn, built.params,
+                           jax.tree.map(jnp.zeros_like, built.pools), SCFG)
+
+    return fresh
 
 
-def test_engine_completes_requests(one_device_mesh):
-    cfg, params, caches, step = _build(one_device_mesh)
-    eng = Engine(step, params, caches, batch=2, max_len=32)
+def test_engine_completes_requests(paged):
+    eng = paged()
     for i in range(3):
         eng.add(Request(prompt=[1, 2, 3], max_new_tokens=4))
     leftover = eng.run(max_steps=30)
     assert leftover == []
 
 
-def test_greedy_decoding_is_deterministic(one_device_mesh):
-    cfg, params, caches0, step = _build(one_device_mesh)
+def test_greedy_decoding_is_deterministic(paged):
     outs = []
     for _ in range(2):
-        caches = jax.tree.map(jnp.copy, caches0)
-        eng = Engine(step, params, caches, batch=2, max_len=32)
+        eng = paged()
         r = Request(prompt=[5, 6, 7], max_new_tokens=5)
         eng.add(r)
         eng.run(max_steps=30)
         outs.append(tuple(r.out_tokens))
     assert outs[0] == outs[1]
     assert len(outs[0]) == 5
-
-
-def test_prefill_with_cache_matches_decode_loop(one_device_mesh):
-    """The batched prefill (one forward pass -> logits + KV caches) must
-    agree with token-by-token decode ingestion, both for the prefill
-    logits AND for the next decode step using the produced caches."""
-    cfg = reduced(ARCHS["granite-3-2b"])
-    model = build_model(cfg, PCFG)
-    params, pspecs = model.init(jax.random.PRNGKey(0), jnp.float32)
-    b, s, s_max = 2, 8, 32
-    toks = np.random.RandomState(1).randint(1, cfg.vocab_size, (b, s + 1)).astype(np.int32)
-
-    pre = jax.jit(jax.shard_map(
-        lambda p, t: model.prefill_with_cache_local(p, t, s_max, None),
-        mesh=one_device_mesh, in_specs=(pspecs, P(None, None)),
-        out_specs=(P(None, None), {"attn": {"k": P(*([None] * 5)),
-                                            "v": P(*([None] * 5))}}),
-        check_vma=False))
-    logits_pre, caches_pre = pre(params, jnp.asarray(toks[:, :s]))
-
-    caches = jax.tree.map(lambda sh: jnp.zeros(sh.shape, sh.dtype),
-                          model.cache_shapes(b, s_max, jnp.float32))
-    cache_specs = jax.tree.map(lambda x: P(*([None] * x.ndim)), caches)
-    step = jax.jit(jax.shard_map(
-        lambda p, c, n, t: model.decode_step_local(p, c, n, t),
-        mesh=one_device_mesh,
-        in_specs=(pspecs, cache_specs, None, P(None, None)),
-        out_specs=(P(None, None), cache_specs), check_vma=False))
-    logits_loop = None
-    for i in range(s):
-        logits_loop, caches = step(params, caches, jnp.int32(i),
-                                   jnp.asarray(toks[:, i:i + 1]))
-    np.testing.assert_allclose(np.asarray(logits_pre), np.asarray(logits_loop),
-                               atol=2e-3, rtol=2e-3)
-    # continue one decode step from BOTH cache states -> same logits
-    nxt = jnp.asarray(toks[:, s:s + 1])
-    l1, _ = step(params, caches_pre, jnp.int32(s), nxt)
-    l2, _ = step(params, caches, jnp.int32(s), nxt)
-    np.testing.assert_allclose(np.asarray(l1), np.asarray(l2), atol=2e-3, rtol=2e-3)
 
 
 def test_decode_matches_prefill_logits(one_device_mesh):
@@ -120,9 +80,8 @@ def test_decode_matches_prefill_logits(one_device_mesh):
     np.testing.assert_allclose(np.asarray(logits), want, atol=2e-3, rtol=2e-3)
 
 
-def test_engine_metrics_counters(one_device_mesh):
-    cfg, params, caches, step = _build(one_device_mesh)
-    eng = Engine(step, params, caches, batch=2, max_len=32)
+def test_engine_metrics_counters(paged):
+    eng = paged()
     for _ in range(3):  # 3 requests on 2 slots -> one queues
         eng.add(Request(prompt=[1, 2, 3], max_new_tokens=4))
     leftover = eng.run(max_steps=30)
@@ -139,35 +98,35 @@ def test_engine_metrics_counters(one_device_mesh):
     assert "Metrics(" in str(m)
 
 
-def test_overlap_modes_report_wire_dtype(one_device_mesh):
+def test_overlap_modes_report_wire_dtype():
     """Serve provenance carries the resolved wire dtype (PR-6 wire axis):
-    always-explicit, f32 default and per-op overrides both visible."""
+    always-explicit, f32 default and per-op overrides both visible, one
+    row per op of each phase's program."""
     from repro.ops.policy import OverlapPolicy
 
-    cfg, params, caches, step = _build(one_device_mesh)
     pcfg = ParallelConfig(dp=1, tp=1, fsdp=False, compute_dtype="float32",
                           param_dtype="float32",
                           overlap=OverlapPolicy(
                               mode="ring", wires=(("ag_matmul", "int8"),)))
-    eng = Engine(step, params, caches, batch=2, max_len=32, pcfg=pcfg)
+    eng = PagedEngine(None, None, None, None, SCFG, pcfg=pcfg)
     modes = eng.overlap_modes()
-    assert set(modes) == set(Engine.OVERLAP_OPS)
-    assert modes["ag_matmul"].endswith("/int8"), modes
-    for op in ("matmul_rs", "a2a_ep", "flash_decode"):
-        assert modes[op].endswith("/f32"), modes
+    assert set(modes) == {"decode:a2a_ep", "decode:flash_decode",
+                          "prefill:ag_matmul", "prefill:matmul_rs"}
+    assert modes["prefill:ag_matmul"].endswith("/int8"), modes
+    for row in ("prefill:matmul_rs", "decode:a2a_ep", "decode:flash_decode"):
+        assert modes[row].endswith("/f32"), modes
     # mode/backend still lead the string
     for desc in modes.values():
         assert len(desc.split("/")) >= 3, desc
 
 
-def test_overlap_modes_empty_without_pcfg(one_device_mesh):
-    cfg, params, caches, step = _build(one_device_mesh)
-    eng = Engine(step, params, caches, batch=2, max_len=32)
+def test_overlap_modes_empty_without_pcfg():
+    eng = PagedEngine(None, None, None, None, SCFG)
     assert eng.overlap_modes() == {}
 
 
 # ---------------------------------------------------------------------------
-# Metrics under contention (fake step fn + fake clock -> hand-computed)
+# Metrics under contention (fake programs + fake clock -> hand-computed)
 # ---------------------------------------------------------------------------
 
 
@@ -182,67 +141,92 @@ class _FakeClock:
         return float(self.t)
 
 
-def test_metrics_under_contention_hand_computed(monkeypatch):
-    """3 requests on 2 slots, prompt 3 + 2 generated each, fake clock.
-
-    Call order is deterministic: adds stamp t=0,1,2; each step stamps
-    one tick (t=3..). A request takes 4 steps — the step feeding the
-    last prompt token also yields the first generated token. Requests
-    1+2 run steps 1-4 (now=3..6), request 3 queues through step 4 and
-    runs steps 5-8 (now=7..10). Hand-computed:
-      ttft r1 = 5-0, r2 = 5-1, r3 = 9-2  (queue wait INCLUDED)
-      tpot    = 1 tick/token for all (excludes the first token)
-      queue samples  [1]*4 + [0]*4   -> mean 0.5, max 1
-      occupancy      [1.]*4 + [.5]*4 -> mean 0.75
-    """
+def _fake_engine(monkeypatch, batch, max_len):
+    """A PagedEngine on the fake clock whose programs return all-zero
+    logits (greedy token 0, never eos) in the paged signatures."""
     import repro.serve.engine as engine_mod
 
     monkeypatch.setattr(engine_mod.time, "perf_counter", _FakeClock())
-    step_fn = lambda p, c, n, t: (np.zeros((2, 16), np.float32), c)
-    eng = Engine(step_fn, params=None, init_caches=None, batch=2, max_len=32)
+
+    def prefill_fn(params, pools, table, starts, n_valid, tokens):
+        return np.zeros((tokens.shape[0], 16), np.float32), pools
+
+    def decode_fn(params, pools, table, lens, active, token):
+        return np.zeros((token.shape[0], 16), np.float32), pools
+
+    scfg = ServeConfig(batch=batch, max_len=max_len, page_size=8, chunk=4,
+                       token_budget=8)
+    return PagedEngine(prefill_fn, decode_fn, None, None, scfg)
+
+
+def test_metrics_under_contention_hand_computed(monkeypatch):
+    """3 requests on 2 slots, prompt 3 + 2 generated each, fake clock.
+
+    Adds stamp arrivals t=0,1,2; each program call then reads one tick
+    after its logits are fetched. One data shard, so at most one
+    prefill chunk a step; a 3-token prompt is one chunk, whose logits
+    carry the first token; in a step the prefill runs before the decode.
+
+      step 1: admit r1->slot 0, r2->slot 1; r3 queues. prefill r1 (t=3,
+              r1 first).                        queue 1, occupancy 2/2
+      step 2: prefill r2 (t=4, r2 first); decode r1 (t=5, r1 done,
+              slot 0 freed).                    queue 1, occupancy 2/2
+      step 3: admit r3->slot 0. prefill r3 (t=6, r3 first); decode r2
+              (t=7, r2 done).                   queue 0, occupancy 2/2
+      step 4: decode r3 (t=8, r3 done).         queue 0, occupancy 1/2
+
+      ttft r1 = 3-0, r2 = 4-1, r3 = 6-2  (r3's queue wait INCLUDED)
+      tpot r1 = 5-3, r2 = 7-4, r3 = 8-6  (excludes the first token)
+      queue samples  [1, 1, 0, 0]        -> mean 0.5, max 1
+      occupancy      [1, 1, 1, .5]       -> mean 0.875
+    """
+    eng = _fake_engine(monkeypatch, batch=2, max_len=32)
     for _ in range(3):
         eng.add(Request(prompt=[1, 2, 3], max_new_tokens=2))
     assert eng.run(max_steps=50) == []
     m = eng.metrics()
     assert m.requests_completed == 3
     assert m.tokens_generated == 6
-    assert m.steps == m.steps_decode == 8
-    assert m.ttft_mean_s == (5 + 4 + 7) / 3
-    assert m.ttft_max_s == 7.0            # r3's queue wait is in its TTFT
-    assert m.tpot_mean_s == 1.0           # (t_done-t_first)/(n_out-1)
+    assert m.steps == 4
+    assert m.steps_prefill == 3 and m.steps_decode == 3
+    assert m.ttft_mean_s == (3 + 3 + 4) / 3
+    assert m.ttft_max_s == 4.0            # r3's queue wait is in its TTFT
+    assert m.tpot_mean_s == (2 + 3 + 2) / 3
     assert m.queue_depth_mean == 0.5
     assert m.queue_depth_max == 1
-    assert m.slot_occupancy_mean == 0.75
+    assert m.slot_occupancy_mean == 0.875
 
 
 def test_truncation_flag_on_capacity(monkeypatch):
-    """A request that hits max_len mid-generation finishes with an
-    explicit truncated flag (no silent stranding) and is counted."""
-    import repro.serve.engine as engine_mod
+    """A request that reaches max_len mid-generation finishes with an
+    explicit truncated flag (no silent stranding) and is counted.
 
-    monkeypatch.setattr(engine_mod.time, "perf_counter", _FakeClock())
-    step_fn = lambda p, c, n, t: (np.zeros((1, 16), np.float32), c)
-    eng = Engine(step_fn, params=None, init_caches=None, batch=1, max_len=4)
+    max_len 6, prompt 3, max_new 8: admission reserves min(3 + 8, 6) = 6
+    tokens, so the slot may generate 6 - 3 = 3. Step 1 prefills the
+    prompt (first token), steps 2 and 3 decode one token each; the
+    third token reaches the budget short of max_new, so the request
+    ends truncated after 3 steps."""
+    eng = _fake_engine(monkeypatch, batch=1, max_len=6)
     req = Request(prompt=[1, 2, 3], max_new_tokens=8)
     eng.add(req)
     assert eng.run(max_steps=20) == []    # finishes despite the tight cache
     assert req.done and req.truncated
-    assert len(req.out_tokens) == 2       # positions 3,4 then capacity
+    assert len(req.out_tokens) == 3       # prompt 3 + 3 generated = max_len
     m = eng.metrics()
+    assert m.steps == 3
     assert m.requests_truncated == 1
     assert m.requests_completed == 1
 
 
 def test_untruncated_requests_keep_flag_clear(monkeypatch):
-    import repro.serve.engine as engine_mod
-
-    monkeypatch.setattr(engine_mod.time, "perf_counter", _FakeClock())
-    step_fn = lambda p, c, n, t: (np.zeros((1, 16), np.float32), c)
-    eng = Engine(step_fn, params=None, init_caches=None, batch=1, max_len=32)
+    """max_len 32 holds prompt 3 + max_new 4: one prefill step and three
+    decode steps emit the 4 tokens, and the request ends on max_new."""
+    eng = _fake_engine(monkeypatch, batch=1, max_len=32)
     req = Request(prompt=[1, 2, 3], max_new_tokens=4)
     eng.add(req)
     eng.run(max_steps=20)
     assert req.done and not req.truncated
+    assert len(req.out_tokens) == 4
     assert eng.metrics().requests_truncated == 0
 
 
@@ -252,34 +236,7 @@ def test_untruncated_requests_keep_flag_clear(monkeypatch):
 # ---------------------------------------------------------------------------
 
 
-def test_slot_reuse_matches_fresh_engine_tokenwise(one_device_mesh):
-    cfg, params, caches0, step = _build(one_device_mesh)
-    probe_prompt = [11, 7, 23, 4]
-
-    reused = Engine(step, params, jax.tree.map(jnp.copy, caches0),
-                    batch=2, max_len=32)
-    for _ in range(3):  # churn: fill + free both slots first
-        reused.add(Request(prompt=[9, 8, 7, 6, 5], max_new_tokens=6))
-    assert reused.run(max_steps=60) == []
-    probe_a = Request(prompt=list(probe_prompt), max_new_tokens=5)
-    reused.add(probe_a)
-    assert reused.run(max_steps=60) == []
-
-    fresh = Engine(step, params, jax.tree.map(jnp.copy, caches0),
-                   batch=2, max_len=32)
-    probe_b = Request(prompt=list(probe_prompt), max_new_tokens=5)
-    fresh.add(probe_b)
-    assert fresh.run(max_steps=60) == []
-    assert probe_a.out_tokens == probe_b.out_tokens  # bit-identical
-
-
-def test_slot_reuse_matches_fresh_engine_paged(one_device_mesh):
-    from repro.launch.serve import build_paged_engine
-    from repro.serve import ServeConfig
-
-    cfg = reduced(ARCHS["granite-3-2b"])
-    scfg = ServeConfig(batch=2, max_len=32, page_size=8, chunk=4,
-                       token_budget=8)
+def test_slot_reuse_matches_fresh_engine_paged(paged):
     probe_prompt = [11, 7, 23, 4, 19, 3]
 
     def probe_tokens(engine, churn: bool):
@@ -292,9 +249,29 @@ def test_slot_reuse_matches_fresh_engine_paged(one_device_mesh):
         assert engine.run() == []
         return probe.out_tokens
 
-    reused = build_paged_engine(cfg, PCFG, scfg, one_device_mesh)
-    fresh = build_paged_engine(cfg, PCFG, scfg, one_device_mesh)
-    assert probe_tokens(reused, churn=True) == probe_tokens(fresh, churn=False)
+    assert (probe_tokens(paged(), churn=True)
+            == probe_tokens(paged(), churn=False))
+
+
+@pytest.mark.parametrize("arch", ["granite-3-2b", "granite-moe-3b-a800m"])
+def test_launch_serve_runs_to_completion(arch):
+    """The serving CLI's ``run`` on one device: every request of the
+    synthetic stream is served, through both the chunked-prefill and
+    the paged-decode program."""
+    import argparse
+
+    from repro.launch import serve as serve_mod
+
+    ns = argparse.Namespace(
+        arch=arch, reduced=True, dp=1, tp=1, no_fsdp=False,
+        dtype="float32", batch=2, max_len=32, requests=3, new_tokens=4,
+        temperature=0.0)
+    eng = serve_mod.run(ns)
+    m = eng.metrics()
+    assert eng.leftover() == []
+    assert m.requests_completed == ns.requests, m
+    assert m.tokens_generated == ns.requests * ns.new_tokens, m
+    assert m.steps_prefill > 0 and m.steps_decode > 0, m
 
 
 def test_paged_decode_step_kernel_matches_reference_path(one_device_mesh,

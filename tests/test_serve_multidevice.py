@@ -1,12 +1,14 @@
-"""Serving engines on multi-device meshes (subprocess — the main pytest
-process keeps 1 device).
+"""The paged serving engine on multi-device meshes (subprocess — the
+main pytest process keeps 1 device).
 
 Two properties per world size:
   * slot-reuse isolation — a probe request decoded after the engine has
-    filled and freed every slot (and, paged, every page) emits tokens
+    filled and freed every slot and every page emits tokens
     bit-identical to the same probe on a fresh engine;
-  * paged == tokenwise — the chunked-prefill + paged-decode path agrees
-    with the legacy dense-cache token-by-token path on greedy tokens.
+  * paged == dense-cache decode — the chunked-prefill + paged-decode
+    path agrees on greedy tokens with a loop over the dense-cache decode
+    program (``build_decode_step``) on the same mesh, which feeds the
+    probe one token at a time and then decodes greedily.
 
 World 4 additionally splits the overlap policy per phase (prefill
 bidir/graph, decode one_shot/graph) to exercise the two-program policy
@@ -20,11 +22,14 @@ from conftest import run_devices
 
 SCRIPT = textwrap.dedent("""
     import dataclasses
+    import jax, jax.numpy as jnp
+    import numpy as np
     from repro.configs import ARCHS, reduced
-    from repro.configs.base import ParallelConfig
+    from repro.configs.base import ParallelConfig, ShapeConfig
     from repro.ops.policy import OverlapPolicy
     from repro.launch.mesh import make_mesh
-    from repro.launch.serve import build_paged_engine, build_tokenwise_engine
+    from repro.launch.serve import build_paged_engine
+    from repro.launch.steps import build_decode_step
     from repro.serve import Request, ServeConfig
 
     DP, TP, SPLIT = {dp}, {tp}, {split}
@@ -61,13 +66,24 @@ SCRIPT = textwrap.dedent("""
     assert a == b, ("paged slot reuse leaked", a, b)
     assert len(a) == 5
 
-    tok = build_tokenwise_engine(cfg, pcfg, scfg.batch, scfg.max_len, mesh)
-    c = probe(tok)
-    churn(tok)
-    d = probe(tok)
-    assert c == d, ("tokenwise slot reuse leaked", c, d)
+    # the dense-cache reference: the probe on every lane, fed one token
+    # a step, then 5 greedy tokens (the paged engine's params: seed 0)
+    dense = build_decode_step(
+        cfg, pcfg, ShapeConfig("serve", seq_len=scfg.max_len,
+                               global_batch=scfg.batch, kind="decode"),
+        mesh, cache_dtype=jnp.float32)
+    params, _ = dense.model.init(jax.random.PRNGKey(0), jnp.float32)
+    caches = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
+                          dense.in_shapes[1])
+    feed, c = list(PROBE), []
+    for pos in range(len(PROBE) + 4):
+        tok = feed[pos] if pos < len(PROBE) else c[-1]
+        logits, caches = dense.fn(params, caches, jnp.int32(pos),
+                                  jnp.full((scfg.batch, 1), tok, jnp.int32))
+        if pos >= len(PROBE) - 1:
+            c.append(int(np.argmax(np.asarray(logits)[0])))
 
-    assert a == c, ("paged != tokenwise", a, c)
+    assert a == c, ("paged != dense-cache decode", a, c)
     print("OK", a)
 """)
 
